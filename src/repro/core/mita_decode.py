@@ -330,7 +330,14 @@ class PagedMiTAState(NamedTuple):
 
     Shapes (R = n_pages * window pool rows, S slots,
     M = pages_per_slot = landmark capacity per slot, K expert width):
-      k_pool, v_pool:   [R + 1, Hkv, d]  row R is a write scratch for
+      k_pool, v_pool:   [R + 1, Hkv, L]  f32, L = `ops.pool_lanes(d)` lanes
+                                         per head row (d, or d rounded up
+                                         to 128-lane tiles on the TPU: the
+                                         kernels DMA one head's row, which
+                                         Mosaic allows only for whole tiles
+                                         of 32-bit words; values written
+                                         from a bf16 model stay bf16-exact);
+                                         row R is a write scratch for
                                          inactive slots / padded tokens
       lm_q, lm_v:       [S, Hkv, M, d]   finalized landmark queries/values
       expert_idx:       [S, Hkv, M, K]   GLOBAL pool rows per expert
@@ -374,10 +381,13 @@ class PagedMiTAState(NamedTuple):
 def init_paged_state(n_kv: int, head_dim: int, n_pages: int, n_slots: int,
                      pages_per_slot: int, cfg: DecodeConfig,
                      dtype=jnp.bfloat16) -> PagedMiTAState:
+    from repro.kernels import ops
+
     rows = n_pages * cfg.window + 1
+    lanes = ops.pool_lanes(head_dim)
     return PagedMiTAState(
-        k_pool=jnp.zeros((rows, n_kv, head_dim), dtype),
-        v_pool=jnp.zeros((rows, n_kv, head_dim), dtype),
+        k_pool=jnp.zeros((rows, n_kv, lanes), jnp.float32),
+        v_pool=jnp.zeros((rows, n_kv, lanes), jnp.float32),
         lm_q=jnp.zeros((n_slots, n_kv, pages_per_slot, head_dim), dtype),
         lm_v=jnp.zeros((n_slots, n_kv, pages_per_slot, head_dim), dtype),
         expert_idx=jnp.zeros((n_slots, n_kv, pages_per_slot, cfg.k),
@@ -409,11 +419,12 @@ def _paged_finalize(state: PagedMiTAState, page_table: jax.Array,
 
     w = cfg.window
     n_slots, _, m_max, _ = state.expert_idx.shape
-    d = state.k_pool.shape[-1]
+    d = state.q_sum.shape[-1]
     ctx = m_max * w
 
     if ops.use_finalize_kernel(
-            cfg.finalize_impl, window=w, m=m_max, k_width=cfg.k, d=d,
+            cfg.finalize_impl, window=w, m=m_max, k_width=cfg.k,
+            d=state.k_pool.shape[-1],
             itemsize=state.k_pool.dtype.itemsize, budget=cfg.vmem_budget):
         lm_q, lm_v, ei, ev, q_sum = ops.paged_finalize(
             state.q_sum, state.lm_q, state.lm_v, state.expert_idx,
@@ -425,9 +436,9 @@ def _paged_finalize(state: PagedMiTAState, page_table: jax.Array,
     # gather only pages covering positions < t_new; unowned table entries
     # redirect to the scratch row (they are masked below either way)
     owned = (t_new + w - 1) // w
-    k_ctx = gather_pages(state.k_pool, page_table, w, owned=owned)
-    v_ctx = gather_pages(state.v_pool, page_table, w, owned=owned)
-    q_lm = (state.q_sum / w).astype(state.k_pool.dtype)  # [S, Hkv, d]
+    k_ctx = gather_pages(state.k_pool, page_table, w, d, owned=owned)
+    v_ctx = gather_pages(state.v_pool, page_table, w, d, owned=owned)
+    q_lm = (state.q_sum / w).astype(state.lm_q.dtype)  # [S, Hkv, d]
 
     scores = jnp.einsum("schd,shd->shc", k_ctx, q_lm) / math.sqrt(d)
     visible = jnp.arange(ctx)[None, None, :] < t_new[:, None, None]
@@ -441,7 +452,8 @@ def _paged_finalize(state: PagedMiTAState, page_table: jax.Array,
         jnp.broadcast_to(ctx_rows[:, None, :], top_loc.shape[:-1] + (ctx,)),
         top_loc, axis=-1)
     p = jax.nn.softmax(scores, axis=-1)
-    v_lm = jnp.einsum("shc,schd->shd", p.astype(state.v_pool.dtype), v_ctx)
+    v_lm = jnp.einsum("shc,schd->shd", p.astype(state.v_pool.dtype), v_ctx
+                      ).astype(state.lm_v.dtype)
 
     i = t_new // w - 1                                   # [S]
     sel = due[:, None] & (jnp.arange(m_max)[None, :] == i[:, None])  # [S, M]
@@ -508,8 +520,9 @@ def mita_paged_decode_step(state: PagedMiTAState, q: jax.Array,
     s_ = min(cfg.s, m_max)
 
     use_kernel = ops.use_paged_kernel(
-        cfg.paged_impl, window=w, m=m_max, k_width=cfg.k, g=g, d=d,
-        itemsize=state.k_pool.dtype.itemsize, budget=cfg.vmem_budget)
+        cfg.paged_impl, window=w, m=m_max, k_width=cfg.k, g=g,
+        d=state.k_pool.shape[-1], itemsize=state.k_pool.dtype.itemsize,
+        budget=cfg.vmem_budget)
 
     # 1. append to the slot's current page, accumulate window query sum
     # (the kernel fuses the append when it also owns the attend)
@@ -543,7 +556,7 @@ def mita_paged_decode_step(state: PagedMiTAState, q: jax.Array,
             q, k_new, v_new, state.lm_q, state.lm_v, state.expert_idx,
             state.expert_valid, state.k_pool, state.v_pool, page_table, t,
             active, m_cnt, window=w, n_route=s_, fuse_append=fuse_append)
-        return out, state._replace(k_pool=kp, v_pool=vp)
+        return out.astype(q.dtype), state._replace(k_pool=kp, v_pool=vp)
 
     # 3. attend: shared + routed + local window (same branch math as
     # `mita_decode_step`, with every cache access routed through the pool)
@@ -561,9 +574,9 @@ def mita_paged_decode_step(state: PagedMiTAState, q: jax.Array,
     rows_valid = jnp.take_along_axis(state.expert_valid, flat_e[..., None],
                                      axis=2)
     rows = rows.reshape(n_slots, hkv, g * s_ * cfg.k)
-    k_sel = gather_pool_rows(state.k_pool, rows).reshape(
+    k_sel = gather_pool_rows(state.k_pool, rows, d).reshape(
         n_slots, hkv, g, s_ * cfg.k, d)
-    v_sel = gather_pool_rows(state.v_pool, rows).reshape(
+    v_sel = gather_pool_rows(state.v_pool, rows, d).reshape(
         n_slots, hkv, g, s_ * cfg.k, d)
     logits = jnp.einsum("shgd,shgkd->shgk", q, k_sel) / math.sqrt(d)
     mask = (rows_valid.reshape(n_slots, hkv, g, s_, cfg.k)
@@ -571,17 +584,17 @@ def mita_paged_decode_step(state: PagedMiTAState, q: jax.Array,
     parts.append(partial_from_logits(logits, v_sel, mask=mask))
 
     # local: the slot's own (current) page
-    k_loc = jnp.swapaxes(
-        gather_pages(state.k_pool, cur_page[:, None], w), 1, 2)  # [S,Hkv,w,d]
-    v_loc = jnp.swapaxes(
-        gather_pages(state.v_pool, cur_page[:, None], w), 1, 2)
+    k_loc = jnp.swapaxes(gather_pages(state.k_pool, cur_page[:, None], w, d),
+                         1, 2)                            # [S, Hkv, w, d]
+    v_loc = jnp.swapaxes(gather_pages(state.v_pool, cur_page[:, None], w, d),
+                         1, 2)
     loc_logits = jnp.einsum("shgd,shwd->shgw", q, k_loc) / math.sqrt(d)
     start = (t // w) * w
     loc_mask = (jnp.arange(w)[None, :] + start[:, None]
                 < t_new[:, None])[:, None, None, :]
     parts.append(partial_from_scores(loc_logits, v_loc, mask=loc_mask))
 
-    out = combine(parts)
+    out = combine(parts).astype(q.dtype)
     return jnp.where(active[:, None, None, None], out, 0.0), state
 
 
@@ -635,6 +648,8 @@ def pack_prefill_into_pages(state: PagedMiTAState, pre: MiTADecodeState,
     final window's ``q_sum`` is carried into the slot, so decode (or a later
     `mita_chunk_prefill` call) resumes the window exactly where the prefill
     left it."""
+    from repro.kernels import ops
+
     w = cfg.window
     c_pre = pre.k_cache.shape[-2]
     if c_pre % w:
@@ -655,8 +670,8 @@ def pack_prefill_into_pages(state: PagedMiTAState, pre: MiTADecodeState,
 
     pad_m = ((0, 0), (0, m_max - m_pre), (0, 0))
     return state._replace(
-        k_pool=state.k_pool.at[dst_rows].set(k_rows.astype(state.k_pool.dtype)),
-        v_pool=state.v_pool.at[dst_rows].set(v_rows.astype(state.v_pool.dtype)),
+        k_pool=ops.scatter_pool_rows(state.k_pool, dst_rows, k_rows),
+        v_pool=ops.scatter_pool_rows(state.v_pool, dst_rows, v_rows),
         lm_q=state.lm_q.at[slot].set(
             jnp.pad(pre.lm_q[0], pad_m).astype(state.lm_q.dtype)),
         lm_v=state.lm_v.at[slot].set(
@@ -722,7 +737,8 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     the rows of pages named by ``page_table``, and the scratch row; landmark
     i of the slot summarizes exactly the tokens of ``page_table[i]``.
     """
-    from repro.kernels.ops import gather_pages, gather_pool_rows
+    from repro.kernels.ops import (gather_pages, gather_pool_rows,
+                                   scatter_pool_rows)
 
     w = cfg.window
     hkv, g, nc, d = q.shape
@@ -736,18 +752,16 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     # 1. append chunk KV to the slot's pages (padding -> scratch row)
     page_idx = jnp.clip(pos // w, 0, m_slot - 1)
     dst = jnp.where(valid_tok, page_table[page_idx] * w + pos % w, scratch)
-    kp = state.k_pool.at[dst].set(
-        jnp.swapaxes(k, 0, 1).astype(state.k_pool.dtype))
-    vp = state.v_pool.at[dst].set(
-        jnp.swapaxes(v, 0, 1).astype(state.v_pool.dtype))
+    kp = scatter_pool_rows(state.k_pool, dst, jnp.swapaxes(k, 0, 1))
+    vp = scatter_pool_rows(state.v_pool, dst, jnp.swapaxes(v, 0, 1))
 
     # gathered slot context in token order: [ctx, Hkv, d] — only pages
     # covering positions < t0 + n_valid are real; later table entries
     # redirect to the scratch row (all reads past the valid prefix are
     # masked below, so this only avoids gathering unowned pages)
     owned = ((t0 + n_valid + w - 1) // w)[None]
-    k_ctx = gather_pages(kp, page_table[None], w, owned=owned)[0]
-    v_ctx = gather_pages(vp, page_table[None], w, owned=owned)[0]
+    k_ctx = gather_pages(kp, page_table[None], w, d, owned=owned)[0]
+    v_ctx = gather_pages(vp, page_table[None], w, d, owned=owned)[0]
 
     # 2. finalize every window the chunk completes (windows [m0, m_new)),
     # resuming the open window's query sum from the previous chunk
@@ -762,7 +776,7 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     resume = (li == m0)[None, :, None] & (t0 % w != 0)
     sums = sums + jnp.where(resume, state.q_sum[slot][:, None, :], 0.0)
 
-    q_lm_new = (sums / w).astype(kp.dtype)          # [Hkv, M, d]
+    q_lm_new = (sums / w).astype(state.lm_q.dtype)  # [Hkv, M, d]
     ends = (li + 1) * w                             # [M] strict window ends
     s_lm = jnp.einsum("chd,hmd->hmc", k_ctx, q_lm_new) / math.sqrt(d)
     vis = jnp.arange(ctx)[None, None, :] < ends[None, :, None]
@@ -772,7 +786,8 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     ctx_rows = (page_table[:, None] * w + jnp.arange(w)[None, :]).reshape(ctx)
     new_rows = ctx_rows[top_loc]                    # ctx idx -> global rows
     p_lm = jax.nn.softmax(s_lm, axis=-1)
-    v_lm_new = jnp.einsum("hmc,chd->hmd", p_lm.astype(vp.dtype), v_ctx)
+    v_lm_new = jnp.einsum("hmc,chd->hmd", p_lm.astype(vp.dtype), v_ctx
+                          ).astype(state.lm_v.dtype)
 
     commit = ((li >= m0) & (li < m_new))[None, :, None]
     lm_q_s = jnp.where(commit, q_lm_new, state.lm_q[slot])
@@ -806,9 +821,9 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     rows = jnp.take_along_axis(ei_s, flat_e[..., None], axis=1)
     rows_valid = jnp.take_along_axis(ev_s, flat_e[..., None], axis=1)
     rows = rows.reshape(hkv, g * nc * s_ * cfg.k)
-    k_sel = gather_pool_rows(kp, rows[None])[0].reshape(
+    k_sel = gather_pool_rows(kp, rows[None], d)[0].reshape(
         hkv, g, nc, s_ * cfg.k, d)
-    v_sel = gather_pool_rows(vp, rows[None])[0].reshape(
+    v_sel = gather_pool_rows(vp, rows[None], d)[0].reshape(
         hkv, g, nc, s_ * cfg.k, d)
     logits = jnp.einsum("hgnd,hgnkd->hgnk", q, k_sel) / math.sqrt(d)
     mask = (rows_valid.reshape(hkv, g, nc, s_, cfg.k)
@@ -826,7 +841,7 @@ def mita_chunk_prefill(state: PagedMiTAState, q: jax.Array, k: jax.Array,
     parts.append(partial_from_logits(loc_logits, v_loc[:, None],
                                      mask=loc_mask))
 
-    out = combine(parts)
+    out = combine(parts).astype(q.dtype)
     return out, state._replace(
         k_pool=kp, v_pool=vp,
         lm_q=state.lm_q.at[slot].set(lm_q_s),
@@ -942,14 +957,15 @@ def mita_batched_chunk_prefill(state: PagedMiTAState, q: jax.Array,
     plm_r = state.pre_lm_q[slots]
     pqs_r = state.pre_q_sum[slots]
 
+    lanes = state.k_pool.shape[-1]
     if ops.use_prefill_kernel(
             cfg.prefill_impl, nc=nc, window=w, m=m_slot, k_width=cfg.k,
-            g=g, d=d, itemsize=pdt.itemsize, budget=cfg.vmem_budget):
-        # the budget also sizes the local-branch tile (static: a budget
+            g=g, d=lanes, itemsize=pdt.itemsize, budget=cfg.vmem_budget):
+        # the budget also sizes the attention tile (static: a budget
         # change retraces, mirroring the dispatch decision itself)
         q_block = ops.select_prefill_q_block(
-            nc, w, m_slot, cfg.k, g, d, itemsize=pdt.itemsize,
-            budget=cfg.vmem_budget) or 0
+            nc, w, m_slot, cfg.k, g, lanes, itemsize=pdt.itemsize,
+            budget=cfg.vmem_budget)
         (out, lm_q_n, lm_v_n, ei_n, ev_n, qs_n, plm_n, pqs_n, kp, vp) = \
             ops.batched_chunk_prefill(
                 q, k, v, lm_q_r, lm_v_r, ei_r, ev_r, qs_r, plm_r, pqs_r,
@@ -964,7 +980,7 @@ def mita_batched_chunk_prefill(state: PagedMiTAState, q: jax.Array,
                 ev_r, qs_r, plm_r, pqs_r, page_table, t0, n_valid, n_train,
                 active, cfg)
 
-    return out, state._replace(
+    return out.astype(q.dtype), state._replace(
         k_pool=kp, v_pool=vp,
         lm_q=state.lm_q.at[slots].set(lm_q_n),
         lm_v=state.lm_v.at[slots].set(lm_v_n),
@@ -1010,17 +1026,18 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
     dst = jnp.where(valid,
                     jnp.take_along_axis(page_table, page_idx, axis=1) * w
                     + pos % w, scratch)
-    kp = k_pool.at[dst.reshape(-1)].set(
-        jnp.swapaxes(k, 1, 2).reshape(-1, hkv, d).astype(pdt))
-    vp = v_pool.at[dst.reshape(-1)].set(
-        jnp.swapaxes(v, 1, 2).reshape(-1, hkv, d).astype(pdt))
+    kp = ops.scatter_pool_rows(k_pool, dst.reshape(-1),
+                               jnp.swapaxes(k, 1, 2).reshape(-1, hkv, d))
+    vp = ops.scatter_pool_rows(v_pool, dst.reshape(-1),
+                               jnp.swapaxes(v, 1, 2).reshape(-1, hkv, d))
 
     # gathered per-row context in token order; unowned table entries
     # redirect to the scratch row (reads past the valid prefix are masked
     # or zero-weighted below either way)
     owned = (t0 + n_valid + w - 1) // w
-    k_ctx = ops.gather_pages(kp, page_table, w, owned=owned)  # [P,ctx,Hkv,d]
-    v_ctx = ops.gather_pages(vp, page_table, w, owned=owned)
+    k_ctx = ops.gather_pages(kp, page_table, w, d,
+                             owned=owned)              # [P, ctx, Hkv, d]
+    v_ctx = ops.gather_pages(vp, page_table, w, d, owned=owned)
 
     ql32 = jnp.mean(q, axis=2).astype(jnp.float32)      # [P, Hkv, nc, d]
 
@@ -1036,7 +1053,7 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
     resume_b = (li[None, :] == m0[:, None]) & (t0 % w != 0)[:, None]
     sums_b = sums_b + jnp.where(resume_b[:, None, :, None],
                                 qs_r[:, :, None, :], 0.0)
-    q_lm_b = (sums_b / w).astype(pdt)                   # [P, Hkv, M, d]
+    q_lm_b = (sums_b / w).astype(lm_q_r.dtype)          # [P, Hkv, M, d]
     wend = (li + 1) * w                                 # [M]
     new_end = t0 + n_valid
     qdone_b = (active[:, None] & (wend[None, :] > t0[:, None])
@@ -1060,7 +1077,7 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
     scommit = (active[:, None] & (ends_b > t0[:, None])
                & (ends_b <= new_end[:, None]))
     sc4 = scommit[:, None, :, None]
-    lm_v_s = jnp.where(sc4, v_lm_b, lm_v_r)
+    lm_v_s = jnp.where(sc4, v_lm_b.astype(lm_v_r.dtype), lm_v_r)
     ei_s = jnp.where(sc4, new_rows, ei_r)
     ev_s = jnp.where(sc4, new_valid, ev_r)
 
@@ -1108,7 +1125,7 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
     sums_a = sums_a + jnp.where(resume_a[:, None, :, None],
                                 pqs_r[:, :, None, :], 0.0)
     q_lm_a = (sums_a / w_a[:, None, None, None].astype(jnp.float32)
-              ).astype(pdt)
+              ).astype(plm_r.dtype)
     ends_a = (li[None, :] + 1) * w_a[:, None]           # [P, M]
     qdone_a = (active[:, None] & (ends_a > t0[:, None])
                & (ends_a <= new_end[:, None])
@@ -1180,15 +1197,16 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
         off = 0 if cfg.external_finalize else 1
         avail_b = ((wend[None, None, :] <= pos[:, :, None] + off)
                    & ~is_tr[:, :, None])
-        shared_b, e_b, eok_b = shared_routed(lm_q_s, lm_v_s, avail_b)
+        shared_b, e_b, eok_b = shared_routed(lm_q_s, lm_v_s.astype(pdt),
+                                             avail_b)
         fe_b = e_b.reshape(p_rows, hkv, g * nc * s_)
         rows_b = jnp.take_along_axis(ei_s, fe_b[..., None], axis=2)
         rv_b = jnp.take_along_axis(ev_s, fe_b[..., None], axis=2)
         k_sel = ops.gather_pool_rows(
-            kp, rows_b.reshape(p_rows, hkv, -1)).reshape(
+            kp, rows_b.reshape(p_rows, hkv, -1), d).reshape(
             p_rows, hkv, g, nc, s_ * cfg.k, d)
         v_sel = ops.gather_pool_rows(
-            vp, rows_b.reshape(p_rows, hkv, -1)).reshape(
+            vp, rows_b.reshape(p_rows, hkv, -1), d).reshape(
             p_rows, hkv, g, nc, s_ * cfg.k, d)
         lg = jnp.einsum("shgnd,shgnkd->shgnk", q, k_sel) / math.sqrt(d)
         routed_b = partial_from_logits(
@@ -1228,6 +1246,7 @@ def _batched_chunk_prefill_xla(k_pool, v_pool, q, k, v, lm_q_r, lm_v_r,
                        l=jnp.where(sel, pa[2], pb[2]))
 
     out = combine([pick(sh_a, sh_b), pick(ro_a, ro_b), local])
-    out = jnp.where(active[:, None, None, None, None], out, 0.0)
+    out = jnp.where(active[:, None, None, None, None], out, 0.0
+                    ).astype(q.dtype)
     return (out, lm_q_s, lm_v_s, ei_s, ev_s, q_sum_s, pre_lm_q_s,
             pre_q_sum_s, kp, vp)

@@ -17,31 +17,27 @@ kernel (`mita_paged_attn.py`).  Each program:
     systems), scores each completed window against the gathered context
     with one in-kernel top-k, and commits landmark queries/values + global
     expert rows exactly where the XLA oracle does;
-  * **chunk attention** — shared + routed + local branches for every chunk
-    position, per-position A/B selection (training vs decode landmark
-    availability), merged with ONE online softmax over the concatenated
-    branch logits — the expert gathers resolve through the VMEM context via
-    exact one-hot matmuls (0·x == 0 and 1·x == x bit-exactly for finite x),
-    so no per-row DMA is needed on this path.
+  * **chunk attention** — tiles of ``q_block`` windows of one query head.
+    Every expert key is a position of the gathered context, so one
+    ``[tile, ctx]`` score matrix serves the routed AND the local branch:
+    each context lane carries a weight — how many of the row's routed
+    experts hold that position (an exact 0/1 matmul of the routing
+    one-hots against a per-landmark membership table) plus one if it lies
+    in the row's local window — and ONE softmax runs over those weighted
+    lanes and the shared-landmark scores (A/B system selected per
+    position).  A key that two branches both hold counts twice, exactly
+    as in the oracle's concatenated-branch softmax.
 
 The XLA path in `core.mita_decode.mita_batched_chunk_prefill` is the
-fallback and the bit-exact oracle: `tests/test_kernel_oracle.py` pins
-pages, landmarks, expert rows, and the resumed q_sum state bit-identical
-(f32 pools) across ragged resume points, non-aligned heads, preemption
-recompute, and inactive slots.
+fallback and the oracle: `tests/test_kernel_oracle.py` pins pages,
+landmarks, expert rows, and the resumed q_sum state bit-identical (f32
+pools) across ragged resume points, non-aligned heads, preemption
+recompute, and inactive slots; outputs agree within f32 rounding.
 
-Per-program VMEM working set (budget-checked by
-`kernels.ops.chunk_prefill_vmem_bytes`): the gathered context ``2·ctx·d``,
-chunk q/k/v/out ``(2G+2)·nc·d``, landmark tiles ``8·M·d``, expert tiles
-``2·M·K·d``, and the f32 score rows.  The local-branch score matrix is
-TILED over query window-groups (static ``q_block`` from
-`kernels.ops.select_prefill_q_block`): each tile of ``q_block`` windows
-scores only a ``(q_block + 2)``-window key slab, so the local term is
-``G·(q_block·w)·kb`` instead of ``G·nc·ctx`` and production chunk shapes
-fit the budget instead of tripping `prefill_kernel_fallbacks`.  Because
-``w_a <= 2w - 1``, every position's whole local window lies inside its
-tile's slab — complete per-position partials, no online-softmax rescale,
-bit-identical at every tile size.
+The pools must be 32-bit: Mosaic DMAs one KV head's row of a
+``[R, Hkv, d]`` pool only when a row is a whole number of 32-bit words
+per head.  Per-program VMEM working set: `kernels.ops.
+chunk_prefill_vmem_bytes`.
 """
 
 from __future__ import annotations
@@ -58,44 +54,87 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
 def _first_argmax(x):
-    """Row-wise (max, first-index-of-max) of [R, C] — the lax.top_k /
-    jnp.argmax tie rule, expressed as two vector reduces."""
+    """Row-wise (max, first-index-of-max) of [R, C] as [R, 1] columns —
+    the lax.top_k / jnp.argmax tie rule, expressed as two vector
+    reduces."""
     c = x.shape[-1]
     cid = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-    mx = jnp.max(x, axis=-1)
-    ix = jnp.min(jnp.where(x == mx[..., None], cid, c), axis=-1)
-    return mx, ix.astype(jnp.int32)
+    mx = jnp.max(x, axis=-1, keepdims=True)
+    ix = jnp.min(jnp.where(x == mx, cid, c), axis=-1, keepdims=True)
+    return mx, ix
 
 
 def _topk(x, k: int):
     """Iterative top-k over the last axis of [R, C]; bit-identical values
     and indices to `jax.lax.top_k` (descending, ties by ascending index).
     Selected lanes are retired with -inf, strictly below the NEG_INF used
-    for masking, so duplicates of NEG_INF still come out in index order."""
-    cid = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-    vals, idxs = [], []
-    for _ in range(k):
+    for masking, so duplicates of NEG_INF still come out in index order.
+    Returns ([R, k] values, [R, k] int32 indices)."""
+    r, c = x.shape
+    cid = jax.lax.broadcasted_iota(jnp.int32, (r, c), 1)
+    kid = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+
+    def body(j, carry):
+        x, vals, idxs = carry
         mx, ix = _first_argmax(x)
-        vals.append(mx)
-        idxs.append(ix)
-        x = jnp.where(cid == ix[..., None], -jnp.inf, x)
-    return jnp.stack(vals, axis=-1), jnp.stack(idxs, axis=-1)
+        vals = jnp.where(kid == j, mx, vals)
+        idxs = jnp.where(kid == j, ix, idxs)
+        return jnp.where(cid == ix, -jnp.inf, x), vals, idxs
+
+    _, vals, idxs = jax.lax.fori_loop(
+        0, k, body, (x, jnp.zeros((r, k), x.dtype),
+                     jnp.zeros((r, k), jnp.int32)))
+    return vals, idxs
 
 
-def _onehot_gather(idx, table):
-    """Exact VMEM gather: rows ``table[idx]`` via a one-hot matmul.
-    idx: [R] int32 (out-of-range -> zero row); table: [C, d]."""
-    c = table.shape[0]
-    oh = (jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], c), 1)
-          == idx[:, None]).astype(jnp.float32)
-    return jax.lax.dot_general(oh, table.astype(jnp.float32),
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+def _ctx_to_rows(loc, pt_ref, s, m_slot: int, w: int):
+    """Context positions [R, C] -> GLOBAL pool rows through the slot's
+    page table: ``pt[loc // w] * w + loc % w`` (page lookup as M exact
+    selects — SMEM scalars broadcast into lanes)."""
+    pg = loc // w
+    page = jnp.zeros_like(loc)
+    for j in range(m_slot):
+        page = jnp.where(pg == j, pt_ref[s, j], page)
+    return page * w + loc % w
+
+
+def _rows_to_ctx(rows, pt_ref, s, m_slot: int, w: int):
+    """GLOBAL pool rows [R, C] -> context positions through the slot's
+    page table (first matching table entry); rows on no page of the slot
+    map to ``m_slot * w``, a lane no context holds."""
+    page = rows // w
+    ordn = jnp.full_like(rows, m_slot)
+    for j in reversed(range(m_slot)):
+        ordn = jnp.where(page == pt_ref[s, j], j, ordn)
+    return jnp.where(ordn < m_slot, ordn * w + rows % w, m_slot * w)
+
+
+def _membership(loc, ok, ctx: int):
+    """[M, ctx] f32 table: 1.0 where landmark row i's expert holds
+    context position c (entries with ``ok`` False, or out of range,
+    hold nothing).  loc/ok: [M, K]; positions within a row are distinct
+    (they come from one top-k)."""
+    m, k = loc.shape
+    cid = jax.lax.broadcasted_iota(jnp.int32, (m, ctx), 1)
+    kid = jax.lax.broadcasted_iota(jnp.int32, (m, k), 1)
+    loc = jnp.where(ok, loc, ctx)
+
+    def body(j, mem):
+        col = jnp.sum(jnp.where(kid == j, loc, 0), axis=-1, keepdims=True)
+        return jnp.where(cid == col, 1.0, mem)
+
+    return jax.lax.fori_loop(0, k, body, jnp.zeros((m, ctx), jnp.float32))
 
 
 def _dot(a, b):
     """[R, d] x [C, d] -> [R, C] f32 contraction over the trailing dim."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _mm(a, b):
+    """[R, C] x [C, d] -> [R, d] f32 matmul."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
@@ -107,29 +146,18 @@ def _softmax(x):
     return un / jnp.sum(un, axis=-1, keepdims=True)
 
 
-def _partial(s, p_zero):
-    """`combine.Partial` statistics of pre-masked scores [R, C]:
-    (m [R], l [R], p [R, C]); ``p_zero`` masks the zeroed lanes exactly as
-    the oracle does (scores == NEG_INF or an explicit mask)."""
-    m = jnp.max(s, axis=-1)
-    safe = jnp.where(m == NEG_INF, 0.0, m)
-    p = jnp.exp(s - safe[:, None])
-    p = jnp.where(p_zero, 0.0, p)
-    return m, jnp.sum(p, axis=-1), p
-
-
 def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
-                  q_ref, k_ref, v_ref, lmq_ref, lmv_ref, ei_ref, ev_ref,
+                  q_ref, k_hbm, v_hbm, lmq_ref, lmv_ref, ei_ref, ev_ref,
                   qs_ref, plmq_ref, pqs_ref, kpool_ref, vpool_ref,
                   o_ref, lmq_o, lmv_o, ei_o, ev_o, qs_o, plmq_o, pqs_o,
                   kp_o, vp_o,
-                  kctx, vctx, sem,
+                  kctx, vctx, mem_a, mem_b, sem,
                   *, window: int, k_width: int, n_route: int,
                   external: bool, q_block: int):
     s = pl.program_id(0)
     h = pl.program_id(1)
     w = window
-    nc = k_ref.shape[2]
+    nc = q_ref.shape[3]
     m_slot = lmq_ref.shape[2]
     g = q_ref.shape[2]
     d = q_ref.shape[4]
@@ -150,28 +178,23 @@ def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
         posn = t0 + n
         page = pt_ref[s, jnp.clip(posn // w, 0, m_slot - 1)]
         row = jnp.where(act & (n < nv), page * w + posn % w, n_rows - 1)
-        ck = pltpu.make_async_copy(k_ref.at[0, 0, n], kp_o.at[row, h], sem)
-        ck.start()
-        ck.wait()
-        cv = pltpu.make_async_copy(v_ref.at[0, 0, n], vp_o.at[row, h], sem)
-        cv.start()
-        cv.wait()
+        for src, dst in ((k_hbm, kp_o), (v_hbm, vp_o)):
+            cp = pltpu.make_async_copy(src.at[s, h, pl.ds(n, 1)],
+                                       dst.at[pl.ds(row, 1), h], sem)
+            cp.start()
+            cp.wait()
         return 0
 
     jax.lax.fori_loop(0, nc, append_row, 0)
 
     # ---- 2. gather the slot's context (token order), patch own rows ----
     def gather_page(mi, _):
-        page = pt_ref[s, mi]
-        base = pl.multiple_of(page * w, w)
-        ck = pltpu.make_async_copy(kp_o.at[pl.ds(base, w), h],
-                                   kctx.at[pl.ds(mi * w, w)], sem)
-        ck.start()
-        ck.wait()
-        cv = pltpu.make_async_copy(vp_o.at[pl.ds(base, w), h],
-                                   vctx.at[pl.ds(mi * w, w)], sem)
-        cv.start()
-        cv.wait()
+        base = pl.multiple_of(pt_ref[s, mi] * w, w)
+        for src, dst in ((kp_o, kctx), (vp_o, vctx)):
+            cp = pltpu.make_async_copy(src.at[pl.ds(base, w), h],
+                                       dst.at[pl.ds(mi * w, w)], sem)
+            cp.start()
+            cp.wait()
         return 0
 
     jax.lax.fori_loop(0, m_slot, gather_page, 0)
@@ -179,32 +202,34 @@ def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
     def patch_row(n, _):
         @pl.when(act & (n < nv))
         def _():
-            kctx[pl.ds(t0 + n, 1)] = k_ref[0, 0, n][None].astype(kctx.dtype)
-            vctx[pl.ds(t0 + n, 1)] = v_ref[0, 0, n][None].astype(vctx.dtype)
+            for src, dst in ((k_hbm, kctx), (v_hbm, vctx)):
+                cp = pltpu.make_async_copy(src.at[s, h, pl.ds(n, 1)],
+                                           dst.at[pl.ds(t0 + n, 1)], sem)
+                cp.start()
+                cp.wait()
         return 0
 
     jax.lax.fori_loop(0, nc, patch_row, 0)
 
-    k_ctx = kctx[...].astype(jnp.float32)               # [ctx, d]
-    v_ctx = vctx[...].astype(jnp.float32)
+    # pool rows are lane-padded past the head dim (`ops.pool_lanes`)
+    k_ctx = kctx[...].astype(jnp.float32)[:, :d]        # [ctx, d]
+    v_ctx = vctx[...].astype(jnp.float32)[:, :d]
     q = q_ref[0, 0].astype(jnp.float32)                 # [G, nc, d]
     ql = jnp.mean(q, axis=0)                            # [nc, d] group pool
 
     nid = jax.lax.broadcasted_iota(jnp.int32, (m_slot, nc), 1)
     lid = jax.lax.broadcasted_iota(jnp.int32, (m_slot, nc), 0)
-    pos_n = t0 + nid[0:1]                               # [1, nc] positions
-    valid_n = act & (nid[0:1] < nv)                     # [1, nc]
-    li = lid[:, 0:1]                                    # [M, 1] landmark ids
+    valid_n = act & (nid < nv)                          # [M, nc]
+    li = jax.lax.broadcasted_iota(jnp.int32, (m_slot, 1), 0)  # landmark ids
     cid = jax.lax.broadcasted_iota(jnp.int32, (m_slot, ctx), 1)
 
     # ---- 3. B system: the decode cache (w-sized windows) ----
     win_b = (t0 + nid) // w
     tok_b = (valid_n & (win_b == lid)).astype(jnp.float32)
-    sums_b = jax.lax.dot_general(tok_b, ql, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    sums_b = _mm(tok_b, ql)                             # [M, d]
     m0 = t0 // w
     resume_b = (li == m0) & (t0 % w != 0)
-    sums_b = sums_b + jnp.where(resume_b, qs_ref[0, 0][None], 0.0)
+    sums_b = sums_b + jnp.where(resume_b, qs_ref[0, 0], 0.0)
     q_lm_b = (sums_b / w).astype(lmq_ref.dtype)         # [M, d]
     wend = (li + 1) * w                                 # [M, 1]
     qdone_b = act & (wend > t0) & (wend <= new_end)
@@ -215,37 +240,27 @@ def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
     s_b = jnp.where(cid < ends_b, s_b, NEG_INF)
     top_vals, top_loc = _topk(s_b, k_width)             # [M, K]
     new_valid = (top_vals > NEG_INF / 2).astype(jnp.int32)
-    pt_vec = jnp.stack([pt_ref[s, j] for j in range(m_slot)])      # [M]
-    ctx_rows = (pt_vec[:, None] * w
-                + jax.lax.broadcasted_iota(jnp.int32, (m_slot, w), 1)
-                ).reshape(1, ctx)                       # [1, ctx]
-    mk_cid = jax.lax.broadcasted_iota(jnp.int32, (m_slot * k_width, ctx), 1)
-    new_rows = jnp.sum(
-        jnp.where(mk_cid == top_loc.reshape(-1)[:, None],
-                  jnp.broadcast_to(ctx_rows, (m_slot * k_width, ctx)), 0),
-        axis=-1).reshape(m_slot, k_width)
+    new_rows = _ctx_to_rows(top_loc, pt_ref, s, m_slot, w)
     p_b = _softmax(s_b)
-    v_lm_b = jax.lax.dot_general(p_b, v_ctx, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32
-                                 ).astype(lmv_ref.dtype)
+    v_lm_b = _mm(p_b, v_ctx).astype(lmv_ref.dtype)
     scommit = act & (ends_b > t0) & (ends_b <= new_end)
     lm_v_s = jnp.where(scommit, v_lm_b, lmv_ref[0, 0])
     ei_s = jnp.where(scommit, new_rows, ei_ref[0, 0])
     ev_s = jnp.where(scommit, new_valid, ev_ref[0, 0])
 
     m_new = new_end // w
-    q_sum_s = jnp.sum(jnp.where(li == m_new, sums_b, 0.0), axis=0)
+    q_sum_s = jnp.sum(jnp.where(li == m_new, sums_b, 0.0), axis=0,
+                      keepdims=True)
     q_sum_s = jnp.where(act, q_sum_s, qs_ref[0, 0])
 
     # ---- 4. A system: the training head's n//m-sized prompt windows ----
-    is_tr_n = pos_n < ntr                               # [1, nc]
+    is_tr_n = (t0 + nid) < ntr                          # [M, nc]
     win_a = (t0 + nid) // w_a
     tok_a = (valid_n & is_tr_n & (win_a == lid)).astype(jnp.float32)
-    sums_a = jax.lax.dot_general(tok_a, ql, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    sums_a = _mm(tok_a, ql)
     m0_a = t0 // w_a
     resume_a = (li == m0_a) & (t0 % w_a != 0) & (t0 < ntr)
-    sums_a = sums_a + jnp.where(resume_a, pqs_ref[0, 0][None], 0.0)
+    sums_a = sums_a + jnp.where(resume_a, pqs_ref[0, 0], 0.0)
     q_lm_a = (sums_a / w_a.astype(jnp.float32)).astype(plmq_ref.dtype)
     ends_a = (li + 1) * w_a                             # [M, 1]
     qdone_a = (act & (ends_a > t0) & (ends_a <= new_end) & (li < m_a))
@@ -254,164 +269,84 @@ def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
     # open-window sum: the resume contribution already sits inside
     # sums_a's open row, so selecting that row reproduces tail + resume
     open_a = new_end // w_a
-    pre_q_sum_s = jnp.sum(jnp.where(li == open_a, sums_a, 0.0), axis=0)
+    pre_q_sum_s = jnp.sum(jnp.where(li == open_a, sums_a, 0.0), axis=0,
+                          keepdims=True)
     pre_q_sum_s = jnp.where(act, pre_q_sum_s, pqs_ref[0, 0])
 
     s_a = _dot(pre_lm_q_s.astype(jnp.float32), k_ctx) / math.sqrt(d)
     s_a = jnp.where((cid < ends_a) & (li < m_a), s_a, NEG_INF)
     tv_a, tl_a = _topk(s_a, k_width)                    # [M, K]
-    val_a = (tv_a > NEG_INF / 2).astype(jnp.float32)
-    k_e_a = _onehot_gather(tl_a.reshape(-1), k_ctx)     # [M*K, d]
-    v_e_a = _onehot_gather(tl_a.reshape(-1), v_ctx)
-    p_a = _softmax(s_a)
-    v_lm_a = jax.lax.dot_general(p_a, v_ctx, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    v_lm_a = _mm(_softmax(s_a), v_ctx)                  # [M, d] f32
 
-    # B expert rows: stored GLOBAL pool rows -> context positions via the
-    # slot's page table (no match -> ctx, i.e. a zero one-hot row; such
-    # rows are expert_valid-masked downstream either way)
-    ei_flat = ei_s.reshape(-1)                          # [M*K]
-    page_of = ei_flat // w
-    eq = pt_vec[None, :] == page_of[:, None]            # [M*K, M]
-    mid = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 1)
-    ordn = jnp.min(jnp.where(eq, mid, m_slot), axis=-1)
-    b_cidx = jnp.where(ordn < m_slot, ordn * w + ei_flat % w, ctx)
-    k_e_b = _onehot_gather(b_cidx, k_ctx)               # [M*K, d]
-    v_e_b = _onehot_gather(b_cidx, v_ctx)
-    val_b = ev_s.reshape(-1).astype(jnp.float32)
+    # expert membership per landmark over context positions: A experts
+    # are top-k positions; B experts are stored GLOBAL pool rows mapped
+    # back through the page table (rows on no page of the slot hold
+    # nothing — such entries are expert_valid-masked in the oracle)
+    mem_a[...] = _membership(tl_a, tv_a > NEG_INF / 2, ctx)
+    mem_b[...] = _membership(_rows_to_ctx(ei_s, pt_ref, s, m_slot, w),
+                             ev_s == 1, ctx)
 
-    # ---- 5. chunk attention: shared + routed + local, A/B per position --
-    q2 = q.reshape(g * nc, d)
-    rows_pos = jnp.broadcast_to(pos_n, (g, nc)).reshape(g * nc, 1)
-    rows_tr = jnp.broadcast_to(is_tr_n, (g, nc)).reshape(g * nc, 1)
-    lm_id = jax.lax.broadcasted_iota(jnp.int32, (g * nc, m_slot), 1)
+    # ---- 5. chunk attention, one tile of q_block windows at a time ----
+    lmq_a = pre_lm_q_s.astype(jnp.float32)
+    lmq_b = lm_q_s.astype(jnp.float32)
+    lmv_b = lm_v_s.astype(jnp.float32)
+    tq = q_block * w
+    n_pt = nc // tq
+    scale = 1.0 / math.sqrt(d)
 
-    def branch(lm_q_sys, v_lm_sys, k_e, v_e, val_e, avail):
-        """Shared + routed partials of one landmark system.
-        avail: [g*nc, M] bool; k_e/v_e: [M*K, d]; val_e: [M*K] f32."""
-        r = _dot(q2, lm_q_sys.astype(jnp.float32)) / math.sqrt(d)
-        r = jnp.where(avail, r, NEG_INF)
-        m_sh, l_sh, p_sh = _partial(r, r == NEG_INF)
-        o_sh = jax.lax.dot_general(p_sh, v_lm_sys,
-                                   (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        lg_parts, mask_parts, v_parts = [], [], []
+    def tile(ti, _):
+        gi = ti // n_pt
+        p0 = pl.multiple_of((ti % n_pt) * tq, tq)
+        qt = q_ref[0, 0, gi, pl.ds(p0, tq), :].astype(jnp.float32)
+        pos = t0 + p0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        tr = pos < ntr                                  # A-system rows
+        lm_i = jax.lax.broadcasted_iota(jnp.int32, (tq, m_slot), 1)
+
+        # shared landmarks (doubling as routing logits), A/B per row
+        avail_a = ((lm_i + 1) * w_a <= pos + 1) & (lm_i < m_a)
+        avail_b = (lm_i + 1) * w <= pos + (0 if external else 1)
+        sh_ok = (tr & avail_a) | (~tr & avail_b)
+        r = jnp.where(tr, _dot(qt, lmq_a), _dot(qt, lmq_b)) * scale
+        r = jnp.where(sh_ok, r, NEG_INF)
+
+        # routed experts: top-s landmarks per row -> per-lane key counts
+        oh = jnp.zeros((tq, m_slot), jnp.float32)
         r_route = r
         for _ in range(n_route):
-            vj, ej = _first_argmax(r_route)             # [g*nc]
-            ok_j = vj > NEG_INF / 2
-            r_route = jnp.where(lm_id == ej[:, None], -jnp.inf, r_route)
-            oh = (lm_id == ej[:, None]).astype(jnp.float32)
-            k_sel = jax.lax.dot_general(
-                oh, k_e.reshape(m_slot, k_width * d),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32
-            ).reshape(g * nc, k_width, d)
-            v_sel = jax.lax.dot_general(
-                oh, v_e.reshape(m_slot, k_width * d),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32
-            ).reshape(g * nc, k_width, d)
-            vmask = jax.lax.dot_general(
-                oh, val_e.reshape(m_slot, k_width),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) > 0.5
-            lg = jax.lax.dot_general(q2, k_sel,
-                                     (((1,), (2,)), ((0,), (0,)))
-                                     ) / math.sqrt(d)   # [g*nc, K]
-            lg_parts.append(lg)
-            mask_parts.append(vmask & ok_j[:, None])
-            v_parts.append(v_sel)
-        lg = jnp.concatenate(lg_parts, axis=-1)         # [g*nc, s*K]
-        mask = jnp.concatenate(mask_parts, axis=-1)
-        vals = jnp.concatenate(v_parts, axis=1)         # [g*nc, s*K, d]
-        lg = jnp.where(mask, lg, NEG_INF)
-        m_ro, l_ro, p_ro = _partial(lg, ~mask)
-        o_ro = jax.lax.dot_general(p_ro, vals,
-                                   (((1,), (1,)), ((0,), (0,))),
-                                   preferred_element_type=jnp.float32)
-        return (m_sh, l_sh, o_sh), (m_ro, l_ro, o_ro)
+            mx, ix = _first_argmax(r_route)
+            hit = lm_i == ix
+            oh = oh + jnp.where(hit & (mx > NEG_INF / 2), 1.0, 0.0)
+            r_route = jnp.where(hit, -jnp.inf, r_route)
+        wts = jnp.where(tr, _mm(oh, mem_a[...]), _mm(oh, mem_b[...]))
 
-    avail_a = ((jnp.transpose(ends_a) <= rows_pos + 1)
-               & (lm_id < m_a) & rows_tr)
-    avail_b = ((jnp.transpose(wend) <= rows_pos + (0 if external else 1))
-               & ~rows_tr)
-    sh_a, ro_a = branch(pre_lm_q_s, v_lm_a, k_e_a, v_e_a,
-                        val_a.reshape(-1), avail_a)
-    sh_b, ro_b = branch(lm_q_s, lm_v_s.astype(jnp.float32), k_e_b, v_e_b,
-                        val_b, avail_b)
+        # local window: [window start, pos] in context coordinates
+        cpos = jax.lax.broadcasted_iota(jnp.int32, (tq, ctx), 1)
+        win = jnp.where(tr, (pos // w_a) * w_a, (pos // w) * w)
+        wts = wts + jnp.where((cpos >= win) & (cpos <= pos), 1.0, 0.0)
 
-    # local branch (ctx index == position).  Untiled (q_block == 0): one
-    # [g*nc, ctx] masked score matrix.  Tiled (q_block > 0, requires
-    # nc % w == 0): queries go in window-groups of q_block windows, each
-    # scoring a (q_block + 2)-window key slab that starts two windows
-    # before the tile — w_a <= 2w - 1, so every position's WHOLE local
-    # window sits inside its tile's slab and no cross-tile merge (and no
-    # rescaling) is needed: each lane is either identical to the untiled
-    # matrix or masked to an exact zero in both, keeping the tiled path
-    # bit-identical to the full-context one.
-    if q_block == 0:
-        s_loc = _dot(q2, k_ctx) / math.sqrt(d)          # [g*nc, ctx]
-        crow = jax.lax.broadcasted_iota(jnp.int32, (g * nc, ctx), 1)
-        win_row = jnp.where(rows_tr, (rows_pos // w_a) * w_a,
-                            (rows_pos // w) * w)
-        lmask = (crow >= win_row) & (crow <= rows_pos)
-        s_loc = jnp.where(lmask, s_loc, NEG_INF)
-        m_lo, l_lo, p_lo = _partial(s_loc, s_loc == NEG_INF)
-        o_lo = jax.lax.dot_general(p_lo, v_ctx, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    else:
-        tw = q_block * w                                # tile width (tokens)
-        kb = min((q_block + 2) * w, ctx)                # key-slab width
-        n_tiles = nc // tw
-        m_parts, l_parts, o_parts = [], [], []
-        for ti in range(n_tiles):
-            p0 = ti * tw
-            qt = q[:, p0:p0 + tw, :].reshape(g * tw, d)
-            tpos = (t0 + p0 + jax.lax.broadcasted_iota(
-                jnp.int32, (g, tw), 1)).reshape(g * tw, 1)
-            ttr = tpos < ntr
-            twin = jnp.where(ttr, (tpos // w_a) * w_a, (tpos // w) * w)
-            # t0 and p0 are both window-aligned, so the slab start is too
-            base = pl.multiple_of(jnp.clip(t0 + p0 - 2 * w, 0, ctx - kb), w)
-            kt = kctx[pl.ds(base, kb)].astype(jnp.float32)
-            vt = vctx[pl.ds(base, kb)].astype(jnp.float32)
-            st = _dot(qt, kt) / math.sqrt(d)            # [g*tw, kb]
-            cpos = base + jax.lax.broadcasted_iota(
-                jnp.int32, (g * tw, kb), 1)
-            st = jnp.where((cpos >= twin) & (cpos <= tpos), st, NEG_INF)
-            m_t, l_t, p_t = _partial(st, st == NEG_INF)
-            o_t = jax.lax.dot_general(p_t, vt, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            m_parts.append(m_t.reshape(g, tw))
-            l_parts.append(l_t.reshape(g, tw))
-            o_parts.append(o_t.reshape(g, tw, d))
-        m_lo = jnp.concatenate(m_parts, axis=1).reshape(g * nc)
-        l_lo = jnp.concatenate(l_parts, axis=1).reshape(g * nc)
-        o_lo = jnp.concatenate(o_parts, axis=1).reshape(g * nc, d)
+        # one softmax over shared landmarks + weighted context lanes
+        sc = _dot(qt, k_ctx) * scale                    # [tq, ctx]
+        ctx_ok = wts > 0.0
+        m_sh = jnp.max(r, axis=-1, keepdims=True)
+        m_cx = jnp.max(jnp.where(ctx_ok, sc, NEG_INF), axis=-1,
+                       keepdims=True)
+        mm = jnp.maximum(m_sh, m_cx)
+        safe = jnp.where(mm == NEG_INF, 0.0, mm)
+        p_sh = jnp.where(sh_ok, jnp.exp(r - safe), 0.0)
+        p_cx = jnp.where(ctx_ok, wts * jnp.exp(sc - safe), 0.0)
+        den = (jnp.sum(p_sh, axis=-1, keepdims=True)
+               + jnp.sum(p_cx, axis=-1, keepdims=True))
+        num = (jnp.where(tr, _mm(p_sh, v_lm_a), _mm(p_sh, lmv_b))
+               + _mm(p_cx, v_ctx))
+        out = jnp.where(den == 0.0, 0.0,
+                        num / jnp.where(den == 0.0, 1.0, den))
+        out = jnp.where(act, out, 0.0)
+        o_ref[0, 0, gi, pl.ds(p0, tq), :] = out.astype(o_ref.dtype)
+        return 0
 
-    # per-position A/B selection, then the oracle's exact `combine`
-    sel = rows_tr[:, 0]
-    m1 = jnp.where(sel, sh_a[0], sh_b[0])
-    l1 = jnp.where(sel, sh_a[1], sh_b[1])
-    o1 = jnp.where(sel[:, None], sh_a[2], sh_b[2])
-    m2 = jnp.where(sel, ro_a[0], ro_b[0])
-    l2 = jnp.where(sel, ro_a[1], ro_b[1])
-    o2 = jnp.where(sel[:, None], ro_a[2], ro_b[2])
-    m_star = jnp.maximum(jnp.maximum(m1, m2), m_lo)
-    safe = jnp.where(m_star == NEG_INF, 0.0, m_star)
-    l_tot = jnp.zeros_like(l1)
-    o_tot = jnp.zeros_like(o1)
-    for m_p, l_p, o_p in ((m1, l1, o1), (m2, l2, o2), (m_lo, l_lo, o_lo)):
-        sc = jnp.exp(jnp.where(m_p == NEG_INF, NEG_INF, m_p - safe))
-        l_tot = l_tot + l_p * sc
-        o_tot = o_tot + o_p * sc[:, None]
-    denom = jnp.where(l_tot == 0.0, 1.0, l_tot)
-    out = jnp.where((l_tot == 0.0)[:, None], 0.0, o_tot / denom[:, None])
-    out = jnp.where(act, out, 0.0)
+    jax.lax.fori_loop(0, g * n_pt, tile, 0)
 
     # ---- 6. write back ----
-    o_ref[0, 0] = out.reshape(g, nc, d).astype(o_ref.dtype)
     lmq_o[0, 0] = lm_q_s
     lmv_o[0, 0] = lm_v_s
     ei_o[0, 0] = ei_s
@@ -424,26 +359,28 @@ def _chunk_kernel(pt_ref, t0_ref, nv_ref, ntr_ref, act_ref,      # SMEM
 @functools.partial(
     jax.jit,
     static_argnames=("window", "k_width", "n_route", "external_finalize",
-                     "q_block", "interpret"))
+                     "q_block", "vmem_limit", "interpret"))
 def mita_chunk_prefill_fused(q, k, v, lm_q, lm_v, expert_idx, expert_valid,
                              q_sum, pre_lm_q, pre_q_sum, k_pool, v_pool,
                              page_table, t0, n_valid, n_train, active,
                              window: int, k_width: int, n_route: int = 1,
                              external_finalize: bool = True,
-                             q_block: int = 0,
+                             q_block: int = 1, vmem_limit: int = 0,
                              interpret: bool = False):
     """Fused batched chunk prefill (+ in-place KV append).
 
     q: [S, Hkv, G, nc, d]; k/v: [S, Hkv, nc, d]; lm_q/lm_v/pre_lm_q:
     [S, Hkv, M, d]; expert_idx: [S, Hkv, M, K] GLOBAL pool rows;
     expert_valid: [S, Hkv, M, K] bool; q_sum/pre_q_sum: [S, Hkv, d] f32;
-    k_pool/v_pool: [R + 1, Hkv, d] (row R is the scratch row); page_table:
-    [S, M] i32; t0/n_valid/n_train: [S] i32; active: [S] bool.
+    k_pool/v_pool: [R + 1, Hkv, L] 32-bit, ``L >= d`` lanes per head row
+    (`kernels.ops.pool_lanes`; row R is the scratch row);
+    page_table: [S, M] i32; t0/n_valid/n_train: [S] i32; active: [S] bool.
 
-    ``q_block`` tiles the local branch (windows per query tile, from
-    `kernels.ops.select_prefill_q_block`; 0 = untiled full-context scores;
-    > 0 requires ``nc % window == 0`` and ``q_block | (nc // window)``) —
-    every tile size is bit-identical to the untiled path.
+    ``q_block`` (windows per attention tile, from
+    `kernels.ops.select_prefill_q_block`) requires ``nc % window == 0``
+    and ``q_block | (nc // window)``; every tile size gives the same
+    state bit-for-bit.  ``vmem_limit`` (bytes, 0 = Mosaic's default) is
+    the kernel's scoped-VMEM limit.
 
     Returns (out, lm_q, lm_v, expert_idx, expert_valid [i32], q_sum,
     pre_lm_q, pre_q_sum, k_pool, v_pool) — the pools aliased in/out, every
@@ -454,70 +391,66 @@ def mita_chunk_prefill_fused(q, k, v, lm_q, lm_v, expert_idx, expert_valid,
     n_slots, hkv, g, nc, d = q.shape
     m_slot, kw = expert_idx.shape[-2:]
     assert kw == k_width
-    if q_block:
-        assert nc % window == 0 and (nc // window) % q_block == 0, \
-            (nc, window, q_block)
+    assert q_block > 0 and nc % (q_block * window) == 0, \
+        (nc, window, q_block)
     pdt = k_pool.dtype
+    if pdt.itemsize != 4:
+        raise ValueError(f"chunk-prefill kernel needs 32-bit pools, got "
+                         f"{pdt}")
+    ctx = m_slot * window
+    lanes = k_pool.shape[-1]
+    pad = ((0, 0), (0, 0), (0, 0), (0, lanes - d))
 
+    def blk(*shape):
+        return pl.BlockSpec((1, 1) + shape,
+                            lambda s, h, *_: (s, h) + (0,) * len(shape))
+
+    state_specs = [blk(m_slot, d), blk(m_slot, d), blk(m_slot, kw),
+                   blk(m_slot, kw), blk(1, d), blk(m_slot, d), blk(1, d)]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n_slots, hkv),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, nc, d), lambda s, h, *_: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, nc, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, nc, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # k_pool (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # v_pool (HBM)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, nc, d), lambda s, h, *_: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
-            pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
+        in_specs=[blk(g, nc, d), any_spec, any_spec, *state_specs,
+                  any_spec, any_spec],     # chunk k/v and pools in HBM
+        out_specs=[blk(g, nc, d), *state_specs, any_spec, any_spec],
         scratch_shapes=[
-            pltpu.VMEM((m_slot * window, d), pdt),
-            pltpu.VMEM((m_slot * window, d), pdt),
+            pltpu.VMEM((ctx, lanes), pdt),
+            pltpu.VMEM((ctx, lanes), pdt),
+            pltpu.VMEM((m_slot, ctx), jnp.float32),
+            pltpu.VMEM((m_slot, ctx), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
     kern = functools.partial(_chunk_kernel, window=window, k_width=k_width,
                              n_route=n_route, external=external_finalize,
                              q_block=q_block)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_slots, hkv, g, nc, d), pdt),
+            jax.ShapeDtypeStruct((n_slots, hkv, g, nc, d), q.dtype),
             jax.ShapeDtypeStruct(lm_q.shape, lm_q.dtype),
             jax.ShapeDtypeStruct(lm_v.shape, lm_v.dtype),
             jax.ShapeDtypeStruct(expert_idx.shape, jnp.int32),
             jax.ShapeDtypeStruct(expert_valid.shape, jnp.int32),
-            jax.ShapeDtypeStruct(q_sum.shape, jnp.float32),
+            jax.ShapeDtypeStruct((n_slots, hkv, 1, d), jnp.float32),
             jax.ShapeDtypeStruct(pre_lm_q.shape, pre_lm_q.dtype),
-            jax.ShapeDtypeStruct(pre_q_sum.shape, jnp.float32),
+            jax.ShapeDtypeStruct((n_slots, hkv, 1, d), jnp.float32),
             jax.ShapeDtypeStruct(k_pool.shape, pdt),
             jax.ShapeDtypeStruct(v_pool.shape, pdt),
         ],
         # operand indices count the 5 scalar-prefetch args
         input_output_aliases={15: 8, 16: 9},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
     )(page_table.astype(jnp.int32), t0.astype(jnp.int32),
       n_valid.astype(jnp.int32), n_train.astype(jnp.int32),
       active.astype(jnp.int32),
-      q, k.astype(pdt), v.astype(pdt), lm_q, lm_v,
+      q, jnp.pad(k.astype(pdt), pad), jnp.pad(v.astype(pdt), pad),
+      lm_q, lm_v,
       expert_idx.astype(jnp.int32), expert_valid.astype(jnp.int32),
-      q_sum, pre_lm_q, pre_q_sum, k_pool, v_pool)
+      q_sum[:, :, None], pre_lm_q, pre_q_sum[:, :, None], k_pool, v_pool)
+    out, lmq, lmv, ei, ev, qs, plmq, pqs, kp, vp = outs
+    return out, lmq, lmv, ei, ev, qs[:, :, 0], plmq, pqs[:, :, 0], kp, vp

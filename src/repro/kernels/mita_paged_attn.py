@@ -15,7 +15,8 @@ program, without ever materializing a contiguous per-slot cache:
     in-kernel from the routing logits, and their stored GLOBAL pool rows
     (`expert_idx`, assigned at finalize time) are gathered row-by-row via
     DMA — the vLLM-style page walk, fused with the attention that consumes
-    it.
+    it.  DMA addresses are scalars: each selected expert's ordinal reaches
+    SMEM through a reduction, and its K row ids are DMA'd HBM→SMEM.
 
 The three branches merge in-kernel with the same guarded online-softmax as
 `repro.core.combine`, so the output equals one softmax over the union of
@@ -24,9 +25,13 @@ all branch keys (paper Alg. 1 line 16).  The XLA gather path in
 (`tests/test_kernel_oracle.py` pins parity over randomized page
 permutations, ragged per-slot progress, and inactive slots).
 
+The pools must be 32-bit, with head rows of whole 128-lane tiles on the
+TPU (`kernels.ops.pool_lanes`): Mosaic DMAs one KV head's row of a
+``[R, Hkv, L]`` pool only then; the kernels read its first ``d`` lanes.
+
 Per-program VMEM working set (budget-checked by `kernels.ops` before
 dispatch): q/out `2·G·d`, landmark tiles `2·M·d`, local page `2·w·d`, one
-expert KV tile `2·K·d`, plus the `M·K` expert index/bias tables.  The
+expert KV tile `2·K·d`, plus the `M·K` expert bias table.  The
 expert-row gathers are double-buffered by default (row i+1's copies are in
 flight while row i's drain — the decode step is DMA-latency bound, not
 bandwidth bound); ``REPRO_DMA_PIPELINE=0`` serializes them for debugging
@@ -65,10 +70,11 @@ def _partial(s):
 
 
 def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
-                  q_ref, kn_ref, vn_ref, lmq_ref, lmv_ref,
-                  ei_ref, eb_ref, kpool_ref, vpool_ref,          # pools: ANY
+                  q_ref, kn_hbm, vn_hbm, lmq_ref, lmv_ref,
+                  eb_ref, ei_hbm, kpool_ref, vpool_ref,          # ANY
                   o_ref, kpout_ref, vpout_ref,
-                  kloc, vloc, ketile, vetile, sem, psem,
+                  knew, vnew, kloc, vloc, ketile, vetile, e_sm, rows_sm,
+                  sem, psem,
                   *, window: int, n_route: int, fuse_append: bool,
                   pipeline: bool, scale: float):
     s = pl.program_id(0)
@@ -83,15 +89,18 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
     # inactive slots append to the trailing scratch row (never read back)
     row_new = jnp.where(act, page0 + ts % w, n_rows - 1)
 
+    # the new row HBM->VMEM (register patch below) and, when fused,
+    # HBM->HBM straight into its pool row
+    for src, dst in ((kn_hbm, knew), (vn_hbm, vnew)):
+        cp = pltpu.make_async_copy(src.at[s, pl.ds(h, 1)], dst, sem)
+        cp.start()
+        cp.wait()
     if fuse_append:
-        cp = pltpu.make_async_copy(kn_ref.at[0, 0], kpout_ref.at[row_new, h],
-                                   sem)
-        cp.start()
-        cp.wait()
-        cp = pltpu.make_async_copy(vn_ref.at[0, 0], vpout_ref.at[row_new, h],
-                                   sem)
-        cp.start()
-        cp.wait()
+        for src, dst in ((kn_hbm, kpout_ref), (vn_hbm, vpout_ref)):
+            cp = pltpu.make_async_copy(src.at[s, pl.ds(h, 1)],
+                                       dst.at[row_new, pl.ds(h, 1)], sem)
+            cp.start()
+            cp.wait()
 
     # local page HBM->VMEM in token order; the appended position is patched
     # from registers so the result never depends on append/read ordering
@@ -101,11 +110,15 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
     cp = pltpu.make_async_copy(vpool_ref.at[pl.ds(page0, w), h], vloc, sem)
     cp.start()
     cp.wait()
-    kloc[pl.ds(ts % w, 1)] = kn_ref[0, 0][None]
-    vloc[pl.ds(ts % w, 1)] = vn_ref[0, 0][None]
-
     q = q_ref[0, 0].astype(jnp.float32) * scale              # [G, d]
     g, d = q.shape
+    # pool rows are lane-padded past the head dim (`ops.pool_lanes`)
+    own = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0) == ts % w
+    k_loc = jnp.where(own, knew[...].astype(jnp.float32),
+                      kloc[...].astype(jnp.float32))[:, :d]   # [w, d]
+    v_loc = jnp.where(own, vnew[...].astype(jnp.float32),
+                      vloc[...].astype(jnp.float32))[:, :d]
+
     m_slot = lmq_ref.shape[2]
     k_width = ketile.shape[0]
 
@@ -121,38 +134,46 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
                                 preferred_element_type=jnp.float32)
 
     # local-window branch: the slot's own page, positions <= t
-    s_loc = jax.lax.dot_general(q, kloc[...].astype(jnp.float32),
-                                (((1,), (1,)), ((), ())),
+    s_loc = jax.lax.dot_general(q, k_loc, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     loc_ids = jax.lax.broadcasted_iota(jnp.int32, (g, w), 1)
     s_loc = jnp.where(loc_ids <= ts % w, s_loc, NEG_INF)
     m_l, l_l, p2 = _partial(s_loc)
-    o_l = jax.lax.dot_general(p2, vloc[...].astype(jnp.float32),
-                              (((1,), (0,)), ((), ())),
+    o_l = jax.lax.dot_general(p2, v_loc, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     m_acc, l_acc, o_acc = _merge(m_acc, l_acc, o_acc, m_l, l_l, o_l)
 
-    # routed experts: top-s of r per query head, expert rows gathered from
-    # the pool by their stored GLOBAL row ids — no page-table lookup needed
+    # routed experts: top-s of r per query head (first index of the max,
+    # the lax.top_k tie rule), expert rows gathered from the pool by their
+    # stored GLOBAL row ids — no page-table lookup needed.  DMA addresses
+    # must be scalars: the expert ordinal goes vector -> SMEM through a
+    # full reduction, and the expert's K row ids are DMA'd HBM -> SMEM.
+    g_ids = jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0)
     r_route = r
     for _ in range(n_route):
-        e_j = jnp.argmax(r_route, axis=-1)                   # [G]
-        ok_j = jnp.max(r_route, axis=-1) > NEG_INF / 2
-        r_route = jnp.where(lm_ids == e_j[:, None], NEG_INF, r_route)
+        mx = jnp.max(r_route, axis=-1, keepdims=True)        # [G, 1]
+        e_j = jnp.min(jnp.where(r_route == mx, lm_ids, m_slot), axis=-1,
+                      keepdims=True)                         # [G, 1]
+        ok_j = mx > NEG_INF / 2
+        r_route = jnp.where(lm_ids == e_j, NEG_INF, r_route)
+        for gi in range(g):
+            e_sm[gi] = jnp.sum(jnp.where(g_ids == gi, e_j, 0))
 
         m_rows, l_rows, o_rows = [], [], []
         for gi in range(g):
-            e_gi = e_j[gi]
-            rows = ei_ref[0, 0, pl.ds(e_gi, 1)][0]           # [K] global rows
-            bias = eb_ref[0, 0, pl.ds(e_gi, 1)][0]           # [K] 0 / NEG_INF
+            e_gi = jnp.minimum(e_sm[gi], m_slot - 1)
+            cp = pltpu.make_async_copy(ei_hbm.at[s, h, e_gi], rows_sm, sem)
+            cp.start()
+            cp.wait()
+            bias = eb_ref[0, 0, pl.ds(e_gi, 1), :]           # [1, K]
 
             def row_copies(kk, slot):
-                row = rows[kk]
-                return (pltpu.make_async_copy(kpool_ref.at[row, h],
-                                              ketile.at[kk],
+                row = rows_sm[kk]
+                return (pltpu.make_async_copy(kpool_ref.at[pl.ds(row, 1), h],
+                                              ketile.at[pl.ds(kk, 1)],
                                               psem.at[slot, 0]),
-                        pltpu.make_async_copy(vpool_ref.at[row, h],
-                                              vetile.at[kk],
+                        pltpu.make_async_copy(vpool_ref.at[pl.ds(row, 1), h],
+                                              vetile.at[pl.ds(kk, 1)],
                                               psem.at[slot, 1]))
 
             if pipeline:
@@ -176,25 +197,23 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
                     return 0
             else:
                 def gather_row(kk, _):
-                    ck = pltpu.make_async_copy(kpool_ref.at[rows[kk], h],
-                                               ketile.at[kk], sem)
+                    ck, cv = row_copies(kk, 0)
                     ck.start()
                     ck.wait()
-                    cv = pltpu.make_async_copy(vpool_ref.at[rows[kk], h],
-                                               vetile.at[kk], sem)
                     cv.start()
                     cv.wait()
                     return 0
 
             jax.lax.fori_loop(0, k_width, gather_row, 0)
             s_e = jax.lax.dot_general(
-                q[gi:gi + 1], ketile[...].astype(jnp.float32),
+                q[gi:gi + 1], ketile[...].astype(jnp.float32)[:, :d],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)          # [1, K]
-            s_e = s_e + bias[None, :]
-            s_e = jnp.where(ok_j[gi], s_e, NEG_INF)
+            s_e = s_e + bias
+            s_e = jnp.where(ok_j[gi:gi + 1], s_e, NEG_INF)
             m_e, l_e, p_e = _partial(s_e)
-            o_e = jax.lax.dot_general(p_e, vetile[...].astype(jnp.float32),
+            o_e = jax.lax.dot_general(p_e,
+                                      vetile[...].astype(jnp.float32)[:, :d],
                                       (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
             m_rows.append(m_e)
@@ -213,7 +232,7 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
 @functools.partial(
     jax.jit,
     static_argnames=("window", "n_route", "fuse_append", "pipeline",
-                     "interpret"))
+                     "vmem_limit", "interpret"))
 def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                          lm_q: jax.Array, lm_v: jax.Array,
                          expert_idx: jax.Array, expert_valid: jax.Array,
@@ -222,18 +241,19 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                          active: jax.Array, m_cnt: jax.Array,
                          window: int, n_route: int = 1,
                          fuse_append: bool = True, pipeline: bool = True,
-                         interpret: bool = False):
+                         vmem_limit: int = 0, interpret: bool = False):
     """Fused paged-decode attention (+ optional in-place KV append).
 
     q: [S, Hkv, G, d]; k_new/v_new: [S, Hkv, d];
     lm_q/lm_v: [S, Hkv, M, d]; expert_idx: [S, Hkv, M, K] GLOBAL pool rows;
-    expert_valid: [S, Hkv, M, K] bool; k_pool/v_pool: [R + 1, Hkv, d]
-    (row R is the inactive-slot write scratch); page_table: [S, M] int32;
+    expert_valid: [S, Hkv, M, K] bool; k_pool/v_pool: [R + 1, Hkv, L]
+    32-bit, ``L >= d`` lanes per head row (`kernels.ops.pool_lanes`; row R
+    is the inactive-slot write scratch); page_table: [S, M] int32;
     t: [S] int32 tokens already cached; active: [S] bool;
     m_cnt: [S] int32 landmarks visible to this step (t//w external-finalize,
     (t+1)//w inline — the caller decides).
 
-    Returns (out [S, Hkv, G, d] in pool dtype, k_pool, v_pool).  The pools
+    Returns (out [S, Hkv, G, d] in q's dtype, k_pool, v_pool).  The pools
     are aliased in/out; with ``fuse_append`` the new row is written at
     ``page_table[s, t//w]*w + t%w`` (scratch row when inactive), otherwise
     they pass through untouched (the caller already appended, e.g. before
@@ -241,6 +261,8 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """
     n_slots, hkv, g, d = q.shape
     m_slot, k_width = expert_idx.shape[-2:]
+    lanes = k_pool.shape[-1]
+    pad = ((0, 0), (0, 0), (0, lanes - d))
     bias = jnp.where(expert_valid, 0.0, NEG_INF).astype(jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -248,27 +270,30 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         grid=(n_slots, hkv),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),      # k_new (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # v_new (HBM)
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, k_width),
                          lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, m_slot, k_width),
-                         lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # k_pool (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # v_pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # expert_idx (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # k_pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # v_pool (HBM)
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, d), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((window, d), k_pool.dtype),
-            pltpu.VMEM((window, d), v_pool.dtype),
-            pltpu.VMEM((k_width, d), k_pool.dtype),
-            pltpu.VMEM((k_width, d), v_pool.dtype),
+            pltpu.VMEM((1, lanes), k_pool.dtype),
+            pltpu.VMEM((1, lanes), v_pool.dtype),
+            pltpu.VMEM((window, lanes), k_pool.dtype),
+            pltpu.VMEM((window, lanes), v_pool.dtype),
+            pltpu.VMEM((k_width, lanes), k_pool.dtype),
+            pltpu.VMEM((k_width, lanes), v_pool.dtype),
+            pltpu.SMEM((g,), jnp.int32),           # routed expert ordinals
+            pltpu.SMEM((k_width,), jnp.int32),     # one expert's row ids
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2, 2)),   # expert-row pipeline pairs
         ],
@@ -280,15 +305,18 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_slots, hkv, g, d), k_pool.dtype),
+            jax.ShapeDtypeStruct((n_slots, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         # operand indices count the 4 scalar-prefetch args
         input_output_aliases={11: 1, 12: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
     )(page_table.astype(jnp.int32), t.astype(jnp.int32),
       active.astype(jnp.int32), m_cnt.astype(jnp.int32),
-      q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
-      lm_q, lm_v, expert_idx.astype(jnp.int32), bias, k_pool, v_pool)
+      q, jnp.pad(k_new.astype(k_pool.dtype), pad),
+      jnp.pad(v_new.astype(v_pool.dtype), pad),
+      lm_q, lm_v, bias, expert_idx.astype(jnp.int32), k_pool, v_pool)
     return out, kp, vp

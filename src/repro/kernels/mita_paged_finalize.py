@@ -15,8 +15,8 @@ still on the XLA gathers (`core.mita_decode._paged_finalize`).  Per
     (the same op the oracle runs on the same f32 accumulator);
   * **expert rebuild** — one in-kernel top-k over the masked landmark
     scores, context positions mapped to GLOBAL pool rows through the page
-    table with an exact masked-iota sum, landmark value via the in-kernel
-    softmax replica;
+    table with one exact select per table entry, landmark value via the
+    in-kernel softmax replica;
   * **commit** — merges the new landmark/expert rows at window ordinal
     ``t_new // w - 1`` for ``due`` slots only and zeroes their q_sum;
     non-due (and inactive) slots pass through bit-exactly.
@@ -26,10 +26,9 @@ and the bit-exact oracle (f32 pools): `tests/test_kernel_oracle.py` pins
 lm_q/lm_v/expert rows/validity/q_sum bit-identical over shuffled page
 tables, ragged per-slot t, and inactive slots.
 
-Per-program VMEM working set (budget-checked by
-`kernels.ops.paged_finalize_vmem_bytes`): the gathered context ``2·ctx·d``,
-landmark in+out tiles ``4·M·d``, q_sum in+out ``4·d`` (f32), and the f32
-score/softmax rows ``2·ctx``.
+The pools must be 32-bit (see `kernels.mita_paged_attn`).  Per-program
+VMEM working set: `kernels.ops.paged_finalize_vmem_bytes` — dominated by
+the gathered context and its f32 working copy.
 """
 
 from __future__ import annotations
@@ -42,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mita_chunk_prefill import NEG_INF, _dot, _softmax, _topk
+from repro.kernels.mita_chunk_prefill import (NEG_INF, _ctx_to_rows, _dot,
+                                              _softmax, _topk)
 
 
 def _finalize_kernel(pt_ref, t_ref, due_ref,                     # SMEM
@@ -77,54 +77,48 @@ def _finalize_kernel(pt_ref, t_ref, due_ref,                     # SMEM
 
     jax.lax.fori_loop(0, m_slot, gather_page, 0)
 
-    k_ctx = kctx[...].astype(jnp.float32)               # [ctx, d]
-    v_ctx = vctx[...].astype(jnp.float32)
+    # pool rows are lane-padded past the head dim (`ops.pool_lanes`)
+    k_ctx = kctx[...].astype(jnp.float32)[:, :d]        # [ctx, d]
+    v_ctx = vctx[...].astype(jnp.float32)[:, :d]
 
     # ---- 2. pool the completed window's queries into the landmark ----
-    q_lm = (qs_ref[0, 0] / w).astype(lmq_ref.dtype)     # [d]
+    q_lm = (qs_ref[0, 0] / w).astype(lmq_ref.dtype)     # [1, d]
 
     # ---- 3. rebuild the top-k expert gather over the visible context ----
-    scores = _dot(q_lm.astype(jnp.float32)[None], k_ctx) / math.sqrt(d)
+    scores = _dot(q_lm.astype(jnp.float32), k_ctx) / math.sqrt(d)
     cid = jax.lax.broadcasted_iota(jnp.int32, (1, ctx), 1)
     scores = jnp.where(cid < t_new, scores, NEG_INF)    # [1, ctx]
     top_vals, top_loc = _topk(scores, k_width)          # [1, K]
-    valid = (top_vals[0] > NEG_INF / 2).astype(jnp.int32)        # [K]
-    pt_vec = jnp.stack([pt_ref[s, j] for j in range(m_slot)])    # [M]
-    ctx_rows = (pt_vec[:, None] * w
-                + jax.lax.broadcasted_iota(jnp.int32, (m_slot, w), 1)
-                ).reshape(1, ctx)                       # [1, ctx]
-    mk = jax.lax.broadcasted_iota(jnp.int32, (k_width, ctx), 1)
-    rows = jnp.sum(
-        jnp.where(mk == top_loc[0][:, None],
-                  jnp.broadcast_to(ctx_rows, (k_width, ctx)), 0),
-        axis=-1)                                        # [K] global rows
+    valid = (top_vals > NEG_INF / 2).astype(jnp.int32)  # [1, K]
+    rows = _ctx_to_rows(top_loc, pt_ref, s, m_slot, w)  # [1, K] global rows
     p = _softmax(scores)                                # [1, ctx]
     v_lm = jax.lax.dot_general(p, v_ctx, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32
-                               )[0].astype(lmv_ref.dtype)        # [d]
+                               ).astype(lmv_ref.dtype)  # [1, d]
 
     # ---- 4. commit at window ordinal t_new//w - 1 for due slots ----
     i = t_new // w - 1
     li = jax.lax.broadcasted_iota(jnp.int32, (m_slot, 1), 0)
     sel = due & (li == i)                               # [M, 1]
-    lmq_o[0, 0] = jnp.where(sel, q_lm[None], lmq_ref[0, 0])
-    lmv_o[0, 0] = jnp.where(sel, v_lm[None], lmv_ref[0, 0])
-    ei_o[0, 0] = jnp.where(sel, rows[None], ei_ref[0, 0])
-    ev_o[0, 0] = jnp.where(sel, valid[None], ev_ref[0, 0])
+    lmq_o[0, 0] = jnp.where(sel, q_lm, lmq_ref[0, 0])
+    lmv_o[0, 0] = jnp.where(sel, v_lm, lmv_ref[0, 0])
+    ei_o[0, 0] = jnp.where(sel, rows, ei_ref[0, 0])
+    ev_o[0, 0] = jnp.where(sel, valid, ev_ref[0, 0])
     qs_o[0, 0] = jnp.where(due, 0.0, qs_ref[0, 0])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "k_width", "interpret"))
+    jax.jit, static_argnames=("window", "k_width", "vmem_limit", "interpret"))
 def mita_paged_finalize_fused(q_sum, lm_q, lm_v, expert_idx, expert_valid,
                               k_pool, v_pool, page_table, t_new, due,
                               window: int, k_width: int,
-                              interpret: bool = False):
+                              vmem_limit: int = 0, interpret: bool = False):
     """Fused paged landmark finalize.
 
     q_sum: [S, Hkv, d] f32; lm_q/lm_v: [S, Hkv, M, d]; expert_idx:
     [S, Hkv, M, K] GLOBAL pool rows; expert_valid: [S, Hkv, M, K] bool;
-    k_pool/v_pool: [R + 1, Hkv, d] (read-only here — finalize never
+    k_pool/v_pool: [R + 1, Hkv, L] 32-bit, ``L >= d`` lanes per head row
+    (`kernels.ops.pool_lanes`; read-only here — finalize never
     writes the pools); page_table: [S, M] i32; t_new: [S] i32 (per-slot
     position AFTER the step); due: [S] bool.
 
@@ -142,30 +136,30 @@ def mita_paged_finalize_fused(q_sum, lm_q, lm_v, expert_idx, expert_valid,
         num_scalar_prefetch=3,
         grid=(n_slots, hkv),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
+            pl.BlockSpec((1, 1, 1, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # k_pool (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # v_pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # k_pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),      # v_pool (HBM)
         ],
         out_specs=[
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, d), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, m_slot, kw), lambda s, h, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda s, h, *_: (s, h, 0)),
+            pl.BlockSpec((1, 1, 1, d), lambda s, h, *_: (s, h, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((m_slot * window, d), pdt),
-            pltpu.VMEM((m_slot * window, d), pdt),
+            pltpu.VMEM((m_slot * window, k_pool.shape[-1]), pdt),
+            pltpu.VMEM((m_slot * window, k_pool.shape[-1]), pdt),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
     kern = functools.partial(_finalize_kernel, window=window,
                              k_width=k_width)
-    return pl.pallas_call(
+    lmq, lmv, ei, ev, qs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
@@ -173,10 +167,13 @@ def mita_paged_finalize_fused(q_sum, lm_q, lm_v, expert_idx, expert_valid,
             jax.ShapeDtypeStruct(lm_v.shape, lm_v.dtype),
             jax.ShapeDtypeStruct(expert_idx.shape, jnp.int32),
             jax.ShapeDtypeStruct(expert_valid.shape, jnp.int32),
-            jax.ShapeDtypeStruct(q_sum.shape, jnp.float32),
+            jax.ShapeDtypeStruct(q_sum[:, :, None].shape, jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
     )(page_table.astype(jnp.int32), t_new.astype(jnp.int32),
       due.astype(jnp.int32),
-      q_sum, lm_q, lm_v, expert_idx.astype(jnp.int32),
+      q_sum[:, :, None], lm_q, lm_v, expert_idx.astype(jnp.int32),
       expert_valid.astype(jnp.int32), k_pool, v_pool)
+    return lmq, lmv, ei, ev, qs[:, :, 0]

@@ -49,6 +49,7 @@ import numpy as np
 from repro.configs.registry import get_arch
 from repro.data import DataConfig, synthetic_batch
 from repro.core import mita_decode as mdec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.models.modules import ModelConfig
 
@@ -116,7 +117,8 @@ def static_generate(params, cfg: ModelConfig, prompts: jnp.ndarray, gen: int,
                     "step_times": step_times}
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serving CLI's arguments (validated)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -192,7 +194,16 @@ def main(argv=None):
     if args.chaos_seed is not None and args.engine != "continuous":
         ap.error("--chaos-seed requires --engine continuous (the fault "
                  "injector wraps the DecodeBackend)")
+    return args
 
+
+def run(args: argparse.Namespace) -> dict:
+    """Serve ``args``' generated requests and print the summary lines.
+
+    Returns what was printed as data: ``seconds``, ``requests``, ``tokens``
+    (generated tokens over finished requests), ``finished`` (the
+    `FinishedRequest` list; continuous engine only) and ``stats`` (the
+    engine's `stats()`; continuous engine only)."""
     arch = get_arch(args.arch, smoke=args.smoke)
     if arch.family not in ("dense", "moe", "vlm", "ssm", "hybrid"):
         raise SystemExit("serve.py drives decoder LMs (attention, ssm, "
@@ -234,6 +245,8 @@ def main(argv=None):
         print(f"decode:  {args.gen - 1} steps, {tm['decode_s']:.3f}s "
               f"({tps:.1f} tok/s, batch={args.batch})")
         sample = gen
+        summary = {"seconds": tm["prefill_s"] + tm["decode_s"],
+                   "requests": args.batch, "tokens": int(gen.size)}
     elif args.engine == "static":
         backend = backends.for_arch(arch, params, ecfg)
         t0 = time.perf_counter()
@@ -244,6 +257,8 @@ def main(argv=None):
               f"+{args.gen} in {dt:.3f}s "
               f"({args.batch * args.gen / dt:.1f} tok/s)")
         sample = gen
+        summary = {"seconds": dt, "requests": args.batch,
+                   "tokens": int(np.asarray(gen).size)}
     else:
         from repro.serve import ChaosBackend, ChaosConfig, Supervisor, \
             SupervisorConfig
@@ -283,7 +298,10 @@ def main(argv=None):
               f"{st['prefill_dispatches']} dispatches, "
               f"preemptions={st['preemptions']}, "
               f"pages_hw={st['pages_high_water']}, "
-              f"kernel_fallbacks={st['prefill_kernel_fallbacks']}, "
+              f"prefill_kernel_fallbacks={st['prefill_kernel_fallbacks']}, "
+              f"paged_kernel_fallbacks={st['paged_kernel_fallbacks']}, "
+              f"finalize_kernel_fallbacks="
+              f"{st['finalize_kernel_fallbacks']}, "
               f"prefix_hits={st['prefix_cache_hits']}, "
               f"pages_shared={st['pages_shared']}, "
               f"spec_accepted={st['spec_accepted']}/"
@@ -296,11 +314,19 @@ def main(argv=None):
         full = [f.tokens for f in done if f.reason == "complete"] \
             or [f.tokens for f in done]
         sample = np.stack(full[:2]) if full[0].size else np.zeros((1, 16))
+        summary = {"seconds": dt, "requests": n_req, "tokens": total,
+                   "finished": done, "stats": st}
     print("sample generations (token ids):")
     for b in range(min(2, sample.shape[0])):
         print(f"  [{b}] {sample[b, :16].tolist()}")
+    return summary
+
+
+def main(argv=None):
+    run(parse_args(argv))
     return 0
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -24,6 +24,7 @@ from repro.configs.registry import SHAPES, ShapeSpec, get_arch
 from repro.data import DataConfig, synthetic_batch
 from repro.distributed import sharding as shd
 from repro.distributed.fault_tolerance import StepTimer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import abstract_params, build_cell, family_fns
 from repro.optim import OptConfig, adamw_init
@@ -112,4 +113,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -385,13 +385,16 @@ class MiTABackend(BackendBase):
                     rid: np.ndarray, temperature: np.ndarray,
                     sample_idx: np.ndarray, key: jax.Array) -> np.ndarray:
         if self._dirty:
-            self._t_dev = jnp.asarray(t)
-            self._md_dev = jnp.asarray(self.m_done)
-            self._pt_dev = jnp.asarray(page_table)
-            self._ac_dev = jnp.asarray(active)
-            self._rid_dev = jnp.asarray(rid)
-            self._tp_dev = jnp.asarray(temperature)
-            self._si_dev = jnp.asarray(sample_idx)
+            # copies: on the CPU `jnp.asarray` may alias the engine's host
+            # arrays, which it updates in place between steps, and a
+            # mirror must hold what was uploaded, as it does on the TPU
+            self._t_dev = jnp.array(t)
+            self._md_dev = jnp.array(self.m_done)
+            self._pt_dev = jnp.array(page_table)
+            self._ac_dev = jnp.array(active)
+            self._rid_dev = jnp.array(rid)
+            self._tp_dev = jnp.array(temperature)
+            self._si_dev = jnp.array(sample_idx)
             self._dirty = False
         # host mirror of the device-side due/m_done transition
         w = self.window

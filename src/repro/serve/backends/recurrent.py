@@ -278,11 +278,14 @@ class _RecurrentBackend(BackendBase):
                     sample_idx: np.ndarray, key: jax.Array) -> np.ndarray:
         del page_table                  # constant-size states: no pages
         if self._dirty:
-            self._t_dev = jnp.asarray(t)
-            self._ac_dev = jnp.asarray(active)
-            self._rid_dev = jnp.asarray(rid)
-            self._tp_dev = jnp.asarray(temperature)
-            self._si_dev = jnp.asarray(sample_idx)
+            # copies: on the CPU `jnp.asarray` may alias the engine's host
+            # arrays, which it updates in place between steps, and a
+            # mirror must hold what was uploaded, as it does on the TPU
+            self._t_dev = jnp.array(t)
+            self._ac_dev = jnp.array(active)
+            self._rid_dev = jnp.array(rid)
+            self._tp_dev = jnp.array(temperature)
+            self._si_dev = jnp.array(sample_idx)
             self._dirty = False
         out, self.states, self._t_dev, self._si_dev = self._decode(
             self.params, self.states, jnp.asarray(tokens_in), self._t_dev,
@@ -335,8 +338,13 @@ class _RecurrentBackend(BackendBase):
             # scan; rollback restores + replays the committed prefix with
             # the same inputs, stashed here
             self._snap = _tree_copy(self.states)
-            self._stress = (tokens_in, t, np.asarray(rid),
-                            np.asarray(temperature), sample_idx, key)
+            # private copies: rollback is dispatched with no read-back,
+            # and the engine advances its own t / sample_idx / active in
+            # place right after it returns.  JAX reads a host array when
+            # its transfer runs, which can be after the computations
+            # queued ahead of it, so a shared array would race the update
+            self._stress = (tokens_in.copy(), t.copy(), np.array(rid),
+                            np.array(temperature), sample_idx.copy(), key)
             toks = np.concatenate([tokens_in[None], np.asarray(drafts)], 0)
             outs, self.states = _spec_tf_fn(self.family, self.cfg, k + 1)(
                 self.params, self.states, jnp.asarray(toks, jnp.int32),
@@ -367,7 +375,8 @@ class _RecurrentBackend(BackendBase):
         if self.spec_mode == "self":
             return                      # drafted state IS the decode state
         tokens_in, t, rid, temp, sample_idx, key = self._stress
-        n = np.where(np.asarray(active), np.asarray(commits), 0)
+        active = np.array(active)       # copied, as in `verify_step`
+        n = np.where(active, np.asarray(commits), 0)
         # the committed prefix of the verify scan consumed exactly
         # [input, verify[0..c-2]] — replaying that stream from the
         # snapshot is bit-identical to having decoded it step by step

@@ -44,7 +44,11 @@ write atomically (tmp + rename, the `checkpoint/manager.py` idiom).
 
 `AllocatorInvariantError` is never retried: page-accounting corruption
 is a scheduler bug, and replaying it would turn an error into state
-corruption.
+corruption.  Neither is a program-build error (`is_program_build_error`:
+JAX tracing, Pallas lowering, or an XLA/Mosaic compile failure): the
+same program fails the same way on every attempt, so retrying only hides
+it, and the ladder's ``xla_forced`` rung would swap a kernel the compiler
+refused for the XLA path without anyone noticing.
 """
 
 from __future__ import annotations
@@ -60,6 +64,23 @@ import numpy as np
 from repro.distributed.fault_tolerance import StepTimer
 from repro.serve.engine import (AllocatorInvariantError, FinishedRequest,
                                 Request, ServingEngine, _WaitEntry)
+
+#: messages of the errors JAX raises when Pallas lowering or the XLA /
+#: Mosaic compiler refuses a program (a runtime fault never carries them)
+_BUILD_ERROR_MARKERS = ("Pallas TPU lowering", "Mosaic failed to compile",
+                        "memory space vmem", "compile permanent error")
+
+
+def is_program_build_error(e: BaseException) -> bool:
+    """Whether ``e`` was raised while JAX traced, lowered or compiled a
+    program rather than while the device ran it: tracing's TypeError /
+    NotImplementedError, and the lowering / compiler refusals named by
+    `_BUILD_ERROR_MARKERS`.  Deterministic — never a supervised fault."""
+    if isinstance(e, (TypeError, NotImplementedError)):
+        return True
+    msg = str(e)
+    return any(m in msg for m in _BUILD_ERROR_MARKERS)
+
 
 #: the degradation ladder, rung per index (stats()["degradation_level"])
 DEGRADATION_RUNGS = ("nominal", "spec_off", "prefix_cache_off",
@@ -148,8 +169,9 @@ class Supervisor:
     def step(self) -> bool:
         """One SUPERVISED engine iteration: retries, quarantines, and
         degrades until the underlying `engine.step()` completes, then
-        returns its result.  Raises `AllocatorInvariantError` immediately
-        and `SupervisionExhausted` when the whole policy is spent."""
+        returns its result.  Raises `AllocatorInvariantError` and program-
+        build errors (`is_program_build_error`) immediately, and
+        `SupervisionExhausted` when the whole policy is spent."""
         eng = self.engine
         while True:
             marker = (eng.steps, eng.prefill_dispatches, len(eng.finished),
@@ -160,6 +182,8 @@ class Supervisor:
             except AllocatorInvariantError:
                 raise
             except Exception as e:      # noqa: BLE001 — supervised domain
+                if is_program_build_error(e):
+                    raise
                 self._handle_fault(e)
                 continue
             self.timer.observe(time.perf_counter() - t0)

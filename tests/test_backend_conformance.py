@@ -202,6 +202,61 @@ def test_speculative_parity_all_modes(cell):
                 assert st["spec_rollbacks"] > 0
 
 
+def _host_array(values, dtype):
+    """``values`` in a fresh 64-byte-aligned numpy array: on the CPU, JAX
+    reads such an array in place instead of copying it, as it may any
+    engine-owned array."""
+    values = np.asarray(values, dtype)
+    buf = np.empty(values.size * values.itemsize + 64, np.uint8)
+    off = -buf.ctypes.data % 64
+    out = buf[off:off + values.size * values.itemsize].view(dtype)
+    out[:] = values
+    return out
+
+
+@pytest.mark.parametrize("name", ["mamba2", "rglru"])
+def test_stress_rollback_owns_its_inputs(name):
+    """`rollback` is dispatched with no read-back, and the engine then
+    advances its host ``t`` / ``sample_idx`` / ``tokens_in`` / ``active``
+    in place.  On the CPU, JAX may read a host array in place when the
+    program runs, which can be after the computations queued ahead of
+    it, so the backend must keep private copies: here the host arrays
+    are overwritten right after `rollback` while a long computation
+    holds the queue, and the rolled-back state must equal the one from
+    untouched inputs."""
+    import jax.numpy as jnp
+
+    cfg, params, mk = _cell(name)
+    ecfg = EngineConfig(n_slots=2, pages_per_slot=4, n_pages=8,
+                        sample_device="fused", spec_k=3, spec_mode="stress")
+    busy = jax.jit(lambda m: m @ m @ m @ m @ m @ m)
+    big = jnp.full((1024, 1024), 1e-3, jnp.float32)
+
+    def rolled(clobber):
+        b = mk(params, cfg, ecfg)
+        tok, t = _host_array([5, 9], np.int32), _host_array([2, 6], np.int32)
+        active = _host_array([True, True], np.bool_)
+        rid, temp = np.array([0, 1], np.int32), np.zeros(2, np.float32)
+        si = _host_array([2, 6], np.int32)
+        spec_len, key = np.array([3, 3], np.int32), jax.random.PRNGKey(1)
+        drafts = b.draft_steps(tok, t, active, None, rid, temp, si, key,
+                               spec_len)
+        b.verify_step(tok, t, active, None, rid, temp, si, key, spec_len,
+                      drafts)
+        hold = busy(big)
+        b.rollback(np.array([1, 2], np.int32), active)
+        if clobber:                 # what the engine does next, in place
+            for a in (tok, t, si):
+                a += 7
+            active[:] = False
+        hold.block_until_ready()
+        return jax.tree.map(np.asarray, b.states)
+
+    ref = rolled(False)
+    for _ in range(3):
+        jax.tree.map(np.testing.assert_array_equal, rolled(True), ref)
+
+
 def test_speculation_contract_surface(cell):
     """Protocol surface: the backend advertises `supports_speculation`,
     `draft_horizon` returns a per-slot nonnegative int array, and the

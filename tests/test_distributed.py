@@ -30,10 +30,11 @@ def run_sub(code: str, devices: int = 8) -> str:
 def test_sharded_train_step_compiles_and_runs():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import get_arch, ShapeSpec
         from repro.launch.steps import build_cell, family_fns
         from repro.optim import adamw_init
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         arch = get_arch("qwen3-0.6b", smoke=True)
         import dataclasses
         # widen smoke so dims divide the 4-way model axis
@@ -69,6 +70,7 @@ def test_sharded_result_matches_single_device():
     loss — GSPMD partitioning must not change semantics."""
     code = """
         import jax, jax.numpy as jnp, numpy as np, dataclasses
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import get_arch, ShapeSpec
         from repro.launch.steps import build_cell, family_fns
         from repro.optim import adamw_init
@@ -80,7 +82,7 @@ def test_sharded_result_matches_single_device():
         fns = family_fns(arch)
         b = synthetic_batch(DataConfig(vocab=256, seq_len=64,
                                        global_batch=4), 0)
-        mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+        mesh = make_mesh(MESH_SHAPE, ("data", "model"))
         cell = build_cell(arch, ShapeSpec("t", "train", 64, 4), mesh)
         with mesh:
             params = jax.jit(fns["init"],
@@ -104,16 +106,17 @@ def test_elastic_retarget_between_meshes():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.fault_tolerance import elastic_retarget
+        from repro.launch.mesh import make_mesh
         from repro.models.modules import ModelConfig, AttnConfig
         from repro.models.transformer import lm_init
         cfg = ModelConfig(n_layers=2, d_model=64, n_heads=4, n_kv=2,
                           d_ff=128, vocab=128,
                           attn=AttnConfig(window=16, k=16))
         params = lm_init(jax.random.PRNGKey(0), cfg)
-        m1 = jax.make_mesh((2, 4), ("data", "model"))
+        m1 = make_mesh((2, 4), ("data", "model"))
         p1 = elastic_retarget(params, m1)
         # "node failure": retarget onto a smaller mesh
-        m2 = jax.make_mesh((1, 2), ("data", "model"))
+        m2 = make_mesh((1, 2), ("data", "model"))
         p2 = elastic_retarget(jax.device_get(p1), m2)
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(p2)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -125,6 +128,7 @@ def test_dryrun_cell_on_test_mesh():
     """The dry-run machinery itself (lower+compile+roofline) on 8 devices."""
     print(run_sub("""
         import jax
+        from repro.launch.mesh import make_mesh
         from repro.configs.registry import get_arch, SHAPES, ShapeSpec
         import dataclasses
         from repro.launch.steps import build_cell
@@ -133,7 +137,7 @@ def test_dryrun_cell_on_test_mesh():
         arch = dataclasses.replace(arch, model=dataclasses.replace(
             arch.model, d_model=128, n_heads=4, n_kv=2, head_dim=32,
             d_ff=256, vocab=256))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cell = build_cell(arch, ShapeSpec("t", "train", 64, 8), mesh)
         with mesh:
             lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,

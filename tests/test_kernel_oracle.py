@@ -313,7 +313,7 @@ def test_gather_pages_owned_redirects_to_scratch():
     hkv, d, w = 2, 4, 4
     pool = jnp.arange(9 * hkv * d, dtype=jnp.float32).reshape(9, hkv, d)
     page_ids = jnp.asarray([[0, 1], [1, 0]], jnp.int32)   # slot 1 unused
-    out = ops.gather_pages(pool, page_ids, w,
+    out = ops.gather_pages(pool, page_ids, w, d,
                            owned=jnp.asarray([1, 2], jnp.int32))
     ref = np.asarray(pool)
     # slot 0: first page real, second page -> scratch row replicated
@@ -323,6 +323,32 @@ def test_gather_pages_owned_redirects_to_scratch():
     # slot 1 owns both pages: untouched
     np.testing.assert_array_equal(
         np.asarray(out)[1], np.concatenate([ref[4:8], ref[0:4]]))
+
+
+def test_pool_row_helpers_hide_lane_padding():
+    """The pool row layout (`ops.pool_lanes`) stays inside `kernels.ops`:
+    `scatter_pool_rows` writes ``[..., Hkv, d]`` rows into lane-padded
+    pools with zero pad lanes, and both gathers return exactly the head
+    dim."""
+    hkv, d, lanes, w = 2, 4, 8, 4
+    pool = jnp.full((9, hkv, lanes), 7.0, jnp.float32)
+    rows = jnp.asarray([[1, 5], [2, 6]], jnp.int32)
+    new = jnp.arange(2 * 2 * hkv * d, dtype=jnp.bfloat16).reshape(
+        2, 2, hkv, d)
+    pool = ops.scatter_pool_rows(pool, rows, new)
+    assert pool.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(pool)[[1, 5, 2, 6], :, :d],
+                                  np.asarray(new, np.float32).reshape(
+                                      4, hkv, d))
+    assert not np.asarray(pool)[[1, 5, 2, 6], :, d:].any()
+    got = ops.gather_pool_rows(pool, jnp.asarray([[[5, 1]] * hkv]), d)
+    assert got.shape == (1, hkv, 2, d)
+    np.testing.assert_array_equal(np.asarray(got)[0, :, 0],
+                                  np.asarray(pool)[5, :, :d])
+    pages = ops.gather_pages(pool, jnp.asarray([[0, 1]], jnp.int32), w, d)
+    assert pages.shape == (1, 2 * w, hkv, d)
+    np.testing.assert_array_equal(np.asarray(pages)[0],
+                                  np.asarray(pool)[:2 * w, :, :d])
 
 
 # ------------------------------------------------ fused chunk-prefill kernel --
@@ -661,6 +687,88 @@ def test_finalize_impl_dispatch(monkeypatch):
         np.testing.assert_array_equal(np.asarray(getattr(st_t, f)),
                                       np.asarray(getattr(st_x, f)),
                                       err_msg=f)
+
+
+def _chunk_once(cfg, lanes_pad, seed=6, s_n=3, m_slot=4, hkv=2, g=2, d=16,
+                chunk=16):
+    """One batched chunk-prefill dispatch from a fresh state (with KV pool
+    rows padded with junk to ``lanes_pad`` lanes when given)."""
+    key = jax.random.PRNGKey(seed)
+    ks = jax.random.split(key, 3)
+    n_pages = s_n * m_slot + 2
+    table = np.random.default_rng(seed).permutation(n_pages)[: s_n * m_slot]
+    st = mdec.init_paged_state(hkv, d, n_pages, s_n, m_slot, cfg,
+                               jnp.float32)
+    if lanes_pad:
+        st = _pad_lanes(st, lanes_pad)
+    return jax.jit(mdec.mita_batched_chunk_prefill, static_argnames="cfg")(
+        st, jax.random.normal(ks[0], (s_n, hkv, g, chunk, d)),
+        jax.random.normal(ks[1], (s_n, hkv, chunk, d)),
+        jax.random.normal(ks[2], (s_n, hkv, chunk, d)),
+        jnp.asarray(table.reshape(s_n, m_slot), jnp.int32),
+        jnp.arange(s_n, dtype=jnp.int32), jnp.zeros(s_n, jnp.int32),
+        jnp.asarray([16, 12, 16], jnp.int32),
+        jnp.asarray([16, 12, 20], jnp.int32),
+        jnp.asarray([True, True, False]), cfg=cfg)
+
+
+def _pad_lanes(st, lanes, junk=1e3):
+    """``st`` with its pool rows padded to ``lanes`` lanes of junk."""
+    pad = ((0, 0), (0, 0), (0, lanes - st.k_pool.shape[-1]))
+    return st._replace(k_pool=jnp.pad(st.k_pool, pad, constant_values=junk),
+                       v_pool=jnp.pad(st.v_pool, pad, constant_values=junk))
+
+
+@pytest.mark.parametrize("kind", ["decode", "finalize", "chunk"])
+def test_lane_padded_pools(kind, monkeypatch):
+    """On the TPU the pools' head rows are padded to whole 128-lane tiles
+    (`ops.pool_lanes`); the kernels and the XLA paths must read only the
+    head dim.  Forced here on the CPU: the decode drive keeps its
+    kernel-vs-XLA parity with padded pools, and finalize / chunk prefill
+    give their unpadded results, on both paths, with junk in the pad
+    lanes (a write zeroes them)."""
+    if kind == "decode":
+        monkeypatch.setattr(ops, "pool_lanes", lambda d: 128)
+        _, st_k = _drive(*_paged_pair(s_route=2, external=True),
+                         offs=[0, 5, 11], n_steps=24)
+        assert st_k.k_pool.shape[-1] == 128
+        assert not np.asarray(st_k.k_pool)[..., 16:].any()
+        return
+    if kind == "finalize":
+        st, pt = _finalize_state(_finalize_pair()[0])
+        args = (pt, jnp.asarray([8, 16, 0, 29], jnp.int32),
+                jnp.asarray([True, True, False, True]))
+        fn = jax.jit(mdec.mita_paged_finalize, static_argnames="cfg")
+        runs = [(fn(st, *args, cfg=cfg), fn(_pad_lanes(st, 128), *args,
+                                            cfg=cfg))
+                for cfg in _finalize_pair()]
+        fields = _FIN_FIELDS
+    else:
+        runs = [(_chunk_once(cfg, 0), _chunk_once(cfg, 128))
+                for cfg in _chunk_pair()]
+        for (o0, _), (o1, _) in runs:
+            np.testing.assert_allclose(np.asarray(o1), np.asarray(o0),
+                                       atol=1e-6)
+        runs = [(r0[1], r1[1]) for r0, r1 in runs]
+        fields = _FIN_FIELDS + ("pre_lm_q", "pre_q_sum")
+    for s0, s1 in runs:
+        for f in fields:
+            np.testing.assert_allclose(np.asarray(getattr(s1, f)),
+                                       np.asarray(getattr(s0, f)),
+                                       atol=1e-6, err_msg=f)
+        np.testing.assert_array_equal(np.asarray(s1.k_pool)[..., :16],
+                                      np.asarray(s0.k_pool))
+
+
+def test_kernels_interpret_only_on_cpu(monkeypatch):
+    """Kernels compile on the TPU, interpret on the CPU backend, and refuse
+    every other backend instead of silently interpreting there."""
+    assert ops.kernel_interpret() is True            # the CPU test backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.kernel_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.kernel_interpret()
 
 
 def test_fallback_counters_reset_and_scope():

@@ -25,7 +25,7 @@ from repro.serve import (AllocatorInvariantError, ChaosBackend, ChaosConfig,
                          EngineConfig, InjectedFault, Request, ServingEngine,
                          Supervisor, SupervisorConfig, SupervisionExhausted)
 from repro.serve.backends.mita import MiTABackend
-from repro.serve.supervisor import DEGRADATION_RUNGS
+from repro.serve.supervisor import DEGRADATION_RUNGS, is_program_build_error
 
 W = 8
 
@@ -234,6 +234,56 @@ def test_allocator_invariant_error_is_never_retried(monkeypatch):
     with pytest.raises(AllocatorInvariantError):
         sup.step()
     assert sup.stats()["retries"] == 0 and sup.n_faults == 0
+
+
+_BUILD_ERRORS = {
+    "mosaic": lambda: jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along "
+        "dimension 1 must be aligned to tiling (8), but is 1."),
+    "vmem": lambda: jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+        "allocating on stack"),
+    "lowering": lambda: ValueError(
+        "The Pallas TPU lowering currently requires that the last two "
+        "dimensions of your block shape are divisible by 8 and 128"),
+    "trace": lambda: TypeError("dot_general requires contracting "
+                               "dimensions to have the same shape"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_BUILD_ERRORS))
+def test_program_build_error_leaves_supervisor_at_once(kind, monkeypatch):
+    """A compile / lowering / tracing error raised from a backend
+    dispatch is deterministic: it leaves `Supervisor.step` on the first
+    attempt with no retry, no quarantine and the ladder untouched (the
+    xla_forced rung must never paper over a kernel the compiler refused),
+    while an injected runtime fault stays a supervised, retried fault."""
+    eng = _engine()
+    sup = Supervisor(eng, SupervisorConfig(max_retries=5))
+    calls = []
+
+    def refuse(*a, **k):
+        calls.append(1)
+        raise _BUILD_ERRORS[kind]()
+
+    for op in ("prefill_chunks", "prefill_chunk", "prefill_group",
+               "decode_step"):
+        monkeypatch.setattr(eng.backend, op, refuse)
+    env_before = os.environ.get("REPRO_PREFILL_IMPL")
+    for r in _requests(SPECS):
+        sup.submit(r)
+    with pytest.raises(type(_BUILD_ERRORS[kind]())):
+        sup.step()
+    st = sup.stats()
+    assert len(calls) == 1
+    assert st["retries"] == 0 and st["degradation_level"] == 0
+    assert st["quarantined"] == 0 and sup.n_faults == 0
+    assert os.environ.get("REPRO_PREFILL_IMPL") == env_before
+    assert is_program_build_error(_BUILD_ERRORS[kind]())
+    assert not is_program_build_error(
+        InjectedFault("decode_step", [0], "transient"))
+    assert not is_program_build_error(jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer"))
 
 
 # -------------------------------------------------- pressure & stragglers --

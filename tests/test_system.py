@@ -29,6 +29,44 @@ def test_train_resume_after_failure(tmp_path):
     assert rc == 0
 
 
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    """`enable_compile_cache`: JAX_COMPILATION_CACHE_DIR, when set, is left
+    to JAX (nothing configured in code); otherwise the fixed in-checkout
+    directory, never a temp/pid/time-derived one."""
+    import pathlib
+
+    import jax
+    from repro.launch import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.enable_compile_cache() == str(cc.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CHECKOUT_CACHE)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert cc.CHECKOUT_CACHE == root / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_serve_driver_continuous_reports_every_fallback_counter(capsys):
+    """The continuous engine's summary line carries all three kernel
+    fallback counters and the supervision counters."""
+    from repro.launch.serve import main
+    rc = main(["--arch", "qwen3-0.6b", "--smoke", "--engine", "continuous",
+               "--batch", "2", "--prompt-len", "32", "--gen", "4",
+               "--prefill-chunk", "16", "--sample-device", "fused"])
+    assert rc == 0
+    out = capsys.readouterr().out
+    for key in ("prefill_kernel_fallbacks=0", "paged_kernel_fallbacks=0",
+                "finalize_kernel_fallbacks=0", "retries=0",
+                "degradation_level=0"):
+        assert key in out, key
+
+
 def test_serve_driver_end_to_end(capsys):
     from repro.launch.serve import main
     rc = main(["--arch", "qwen3-0.6b", "--smoke", "--batch", "2",
