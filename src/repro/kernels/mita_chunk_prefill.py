@@ -445,6 +445,7 @@ def mita_chunk_prefill_fused(q, k, v, lm_q, lm_v, expert_idx, expert_valid,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
+        name="mita_chunk_prefill_fused",
     )(page_table.astype(jnp.int32), t0.astype(jnp.int32),
       n_valid.astype(jnp.int32), n_train.astype(jnp.int32),
       active.astype(jnp.int32),

@@ -314,6 +314,7 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
+        name="mita_paged_attention",
     )(page_table.astype(jnp.int32), t.astype(jnp.int32),
       active.astype(jnp.int32), m_cnt.astype(jnp.int32),
       q, jnp.pad(k_new.astype(k_pool.dtype), pad),

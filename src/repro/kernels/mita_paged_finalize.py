@@ -172,6 +172,7 @@ def mita_paged_finalize_fused(q_sum, lm_q, lm_v, expert_idx, expert_valid,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit or None),
         interpret=interpret,
+        name="mita_paged_finalize_fused",
     )(page_table.astype(jnp.int32), t_new.astype(jnp.int32),
       due.astype(jnp.int32),
       q_sum[:, :, None], lm_q, lm_v, expert_idx.astype(jnp.int32),

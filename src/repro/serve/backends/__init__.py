@@ -45,7 +45,9 @@ Protocol (duck-typed; `BackendBase` supplies the defaults):
   * ``invalidate()``            — host copies of scheduler tensors changed;
                                   re-upload device mirrors next step.
   * ``stats()``                 — per-backend counters (dispatches,
-                                  kernel fallbacks) merged into
+                                  active slots and prefill rows/tokens
+                                  dispatched, mirror rebuilds, kernel
+                                  fallbacks) merged into
                                   ``ServingEngine.stats()``.
   * ``static_reference(...)``   — the backend's static/full-forward oracle;
                                   engine greedy tokens must be bit-identical.
@@ -118,7 +120,8 @@ ENGINE_STAT_KEYS = frozenset({
     "degradation_level",
 })
 BACKEND_STAT_KEYS = frozenset({
-    "decode_dispatches", "prefill_kernel_fallbacks",
+    "decode_dispatches", "decode_slot_steps", "prefill_rows",
+    "prefill_tokens", "mirror_uploads", "prefill_kernel_fallbacks",
     "paged_kernel_fallbacks", "finalize_kernel_fallbacks",
 })
 STATS_SCHEMA = ENGINE_STAT_KEYS | BACKEND_STAT_KEYS
@@ -159,6 +162,10 @@ class BackendBase:
         self.model_cfg = cfg
         self.ecfg = ecfg
         self.decode_dispatches = 0
+        self.decode_slot_steps = 0      # active slots, summed per dispatch
+        self.prefill_rows = 0           # chunk-prefill job rows
+        self.prefill_tokens = 0         # ...and their valid prompt tokens
+        self.mirror_uploads = 0         # decode steps that rebuilt mirrors
         self._dirty = True
 
     def fresh(self) -> "BackendBase":
@@ -222,6 +229,17 @@ class BackendBase:
     def invalidate(self) -> None:
         self._dirty = True
 
+    def _count_decode(self, n_active) -> None:
+        """One decode-side dispatch over ``n_active`` active slots."""
+        self.decode_dispatches += 1
+        self.decode_slot_steps += int(n_active)
+
+    def _count_prefill(self, n_valid) -> None:
+        """One chunk-prefill dispatch: ``n_valid`` holds each job row's
+        valid prompt tokens."""
+        self.prefill_rows += len(n_valid)
+        self.prefill_tokens += int(np.sum(n_valid))
+
     # --- supervision hooks (serve/supervisor.py) -------------------------
     # No-op by default: the supervisor notifies the backend of fault-
     # isolation events so wrappers (serve/chaos.py) can key fault
@@ -243,6 +261,10 @@ class BackendBase:
         # rather than inheriting another engine's trace-time fallbacks
         # (keys must cover BACKEND_STAT_KEYS exactly)
         return {"decode_dispatches": self.decode_dispatches,
+                "decode_slot_steps": self.decode_slot_steps,
+                "prefill_rows": self.prefill_rows,
+                "prefill_tokens": self.prefill_tokens,
+                "mirror_uploads": self.mirror_uploads,
                 "prefill_kernel_fallbacks": 0,
                 "paged_kernel_fallbacks": 0,
                 "finalize_kernel_fallbacks": 0}

@@ -9,6 +9,10 @@ the per-job and batched chunk-prefill programs (fused Pallas kernel vs XLA
 dispatch inside, `kernels.ops.use_prefill_kernel`), and the per-slot
 ``m_done`` finalize bookkeeping with its device mirrors.
 
+Each jitted program is named by its inner function, so the profiler's
+trace shows it as ``jit_<name>`` (``jit_mita_decode_step``,
+``jit_mita_batched_chunk_prefill``, ...; docs/serving.md, Observability).
+
 The scheduler sees none of it: it talks the `DecodeBackend` protocol
 (`serve.backends`), and this module translates protocol calls into the
 compiled programs documented in docs/serving.md.
@@ -27,6 +31,7 @@ import numpy as np
 from repro.core import mita_decode as mdec
 from repro.models import transformer as tfm
 from repro.models.modules import ModelConfig
+from repro.serve import spans
 from repro.serve.backends import BackendBase
 
 
@@ -45,7 +50,7 @@ def _decode_fn(cfg: ModelConfig, fused_finalize: bool,
     sampler."""
     w = cfg.attn.window
 
-    def step(p, st, tok, t, m_done, pt, ac, rid, si, temp, key):
+    def mita_decode_step(p, st, tok, t, m_done, pt, ac, rid, si, temp, key):
         due = None
         if fused_finalize:
             due = ac & (t % w == 0) & (t // w > m_done)
@@ -56,7 +61,7 @@ def _decode_fn(cfg: ModelConfig, fused_finalize: bool,
         adv = ac.astype(t.dtype)
         return out, st, t + adv, m_done, si + adv
 
-    return jax.jit(step, donate_argnums=(1, 3, 4, 8))
+    return jax.jit(mita_decode_step, donate_argnums=(1, 3, 4, 8))
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,7 +71,7 @@ def _prefill_pack_fn(cfg: ModelConfig, cap: int, k: int) -> Callable:
     size).  Prefill rows are independent, so batching admissions does not
     change any request's tokens."""
 
-    def prefill_pack(p, st, toks, slots, pages):
+    def mita_prefill_pack(p, st, toks, slots, pages):
         logits, pre = tfm.lm_prefill(p, toks, cfg, cap)
         for i in range(k):
             pre_i = jax.tree.map(
@@ -75,7 +80,7 @@ def _prefill_pack_fn(cfg: ModelConfig, cap: int, k: int) -> Callable:
                                               cfg)
         return logits, st
 
-    return jax.jit(prefill_pack, donate_argnums=(1,))
+    return jax.jit(mita_prefill_pack, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -85,11 +90,11 @@ def _chunk_prefill_fn(cfg: ModelConfig, chunk: int, m_slot: int) -> Callable:
     every request — resume point, validity, and the training/decode
     semantics boundary are data."""
 
-    def run(p, st, toks, slot, pt_row, t0, n_valid, n_train):
+    def mita_chunk_prefill(p, st, toks, slot, pt_row, t0, n_valid, n_train):
         return tfm.lm_prefill_chunk(p, st, toks, slot, pt_row, t0, n_valid,
                                     n_train, cfg)
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(mita_chunk_prefill, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -104,11 +109,12 @@ def _batched_chunk_prefill_fn(cfg: ModelConfig, chunk: int,
     landmark quirk is per-slot data;
     `core.mita_decode.mita_batched_chunk_prefill`)."""
 
-    def run(p, st, toks, job_active, pt, slots, t0, n_valid, n_train):
+    def mita_batched_chunk_prefill(p, st, toks, job_active, pt, slots, t0,
+                                   n_valid, n_train):
         return tfm.lm_prefill_chunks(p, st, toks, job_active, pt, slots,
                                      t0, n_valid, n_train, cfg)
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(mita_batched_chunk_prefill, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -118,11 +124,11 @@ def _draft_fn(cfg: ModelConfig, n_pos: int) -> Callable:
     draft``).  Read-only — no donation, no state output: a rejected draft
     has nothing to undo."""
 
-    def run(p, st, tok, t, ac, m_cnt, rid, si, temp, key):
+    def mita_draft(p, st, tok, t, ac, m_cnt, rid, si, temp, key):
         return tfm.lm_landmark_draft(p, st, tok, t, ac, m_cnt, cfg, n_pos,
                                      rid, si, temp, key)
 
-    return jax.jit(run)
+    return jax.jit(mita_draft)
 
 
 @functools.lru_cache(maxsize=None)
@@ -139,7 +145,8 @@ def _verify_fn(cfg: ModelConfig, fused_finalize: bool,
     overwritten by future appends; no page churn)."""
     w = cfg.attn.window
 
-    def run(p, st, toks, t, m_done, pt, ac, rid, si, temp, key, spec_len):
+    def mita_verify(p, st, toks, t, m_done, pt, ac, rid, si, temp, key,
+                    spec_len):
         def body(carry, inp):
             st, t, m_done, si = carry
             i, tok = inp
@@ -158,7 +165,7 @@ def _verify_fn(cfg: ModelConfig, fused_finalize: bool,
             body, (st, t, m_done, si), (jnp.arange(n_pos), toks))
         return toks_out, q_stack, st
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(mita_verify, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -169,13 +176,13 @@ def _rollback_fn(cfg: ModelConfig) -> Callable:
     slots pass commits=1, whose stack row equals their untouched sums
     because the verify scan's accumulate and finalize are active-masked)."""
 
-    def run(st, q_stack, commits):
+    def mita_rollback(st, q_stack, commits):
         sel = jnp.moveaxis(q_stack, 2, 0)            # [S, k+1, L, Hkv, d]
         idx = (commits - 1)[:, None, None, None, None]
         picked = jnp.take_along_axis(sel, idx, axis=1)[:, 0]
         return st._replace(q_sum=jnp.moveaxis(picked, 0, 1))
 
-    return jax.jit(run, donate_argnums=(0,))
+    return jax.jit(mita_rollback, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -191,7 +198,7 @@ def _attach_prefix_fn(cfg: ModelConfig) -> Callable:
     attached prefix are zeros, masked by landmark availability exactly
     like a retired slot's stale rows."""
 
-    def attach(st, slot, lm_q, lm_v, ei, ev):
+    def mita_attach_prefix(st, slot, lm_q, lm_v, ei, ev):
         zero = jnp.zeros(st.q_sum.shape[:1] + st.q_sum.shape[2:],
                          st.q_sum.dtype)
         return st._replace(
@@ -203,7 +210,7 @@ def _attach_prefix_fn(cfg: ModelConfig) -> Callable:
             q_sum=st.q_sum.at[:, slot].set(zero),
             pre_q_sum=st.pre_q_sum.at[:, slot].set(zero))
 
-    return jax.jit(attach, donate_argnums=(0,))
+    return jax.jit(mita_attach_prefix, donate_argnums=(0,))
 
 
 class MiTABackend(BackendBase):
@@ -295,22 +302,25 @@ class MiTABackend(BackendBase):
                       pages_list: list[list[int]]) -> np.ndarray:
         k, n = prompts.shape
         cap = mdec.window_aligned(n, self.window)
-        logits, self.states = _prefill_pack_fn(self.cfg, cap, k)(
-            self.params, self.states, jnp.asarray(prompts, jnp.int32),
-            jnp.asarray(slots, jnp.int32),
-            jnp.asarray(np.stack(
-                [pg[: cap // self.window] for pg in pages_list]), jnp.int32))
-        return np.asarray(logits)
+        with spans.span("backend.prefill", rows=k, tokens=k * n):
+            logits, self.states = _prefill_pack_fn(self.cfg, cap, k)(
+                self.params, self.states, jnp.asarray(prompts, jnp.int32),
+                jnp.asarray(slots, jnp.int32),
+                jnp.asarray(np.stack([pg[: cap // self.window]
+                                      for pg in pages_list]), jnp.int32))
+            return spans.download(logits)
 
     def prefill_chunk(self, slot: int, pt_row: np.ndarray, toks: np.ndarray,
                       t0: int, n_valid: int, n_train: int) -> np.ndarray:
         fn = _chunk_prefill_fn(self.cfg, self.ecfg.prefill_chunk,
                                self.ecfg.pages_per_slot)
-        logits, self.states = fn(
-            self.params, self.states, jnp.asarray(toks), np.int32(slot),
-            jnp.asarray(pt_row), np.int32(t0), np.int32(n_valid),
-            np.int32(n_train))
-        return np.asarray(logits)
+        with spans.span("backend.prefill", rows=1, tokens=n_valid):
+            logits, self.states = fn(
+                self.params, self.states, jnp.asarray(toks), np.int32(slot),
+                jnp.asarray(pt_row), np.int32(t0), np.int32(n_valid),
+                np.int32(n_train))
+            self._count_prefill([n_valid])
+            return spans.download(logits)
 
     def prefill_chunks(self, slot_ids: list[int], toks: np.ndarray,
                        job_active: np.ndarray, page_table: np.ndarray,
@@ -318,12 +328,16 @@ class MiTABackend(BackendBase):
                        n_train: np.ndarray) -> np.ndarray:
         fn = _batched_chunk_prefill_fn(self.cfg, self.ecfg.prefill_chunk,
                                        self.ecfg.pages_per_slot)
-        logits, self.states = fn(
-            self.params, self.states, jnp.asarray(toks),
-            jnp.asarray(job_active), jnp.asarray(page_table),
-            jnp.asarray(slot_ids, jnp.int32).reshape(len(slot_ids)),
-            jnp.asarray(t0), jnp.asarray(n_valid), jnp.asarray(n_train))
-        return np.asarray(logits)
+        valid = np.asarray(n_valid)[np.asarray(job_active)]
+        with spans.span("backend.prefill", rows=len(valid),
+                        tokens=int(valid.sum())):
+            logits, self.states = fn(
+                self.params, self.states, jnp.asarray(toks),
+                jnp.asarray(job_active), jnp.asarray(page_table),
+                jnp.asarray(slot_ids, jnp.int32).reshape(len(slot_ids)),
+                jnp.asarray(t0), jnp.asarray(n_valid), jnp.asarray(n_train))
+            self._count_prefill(valid)
+            return spans.download(logits)
 
     # ------------------------------------------------------ slot lifecycle --
 
@@ -384,32 +398,37 @@ class MiTABackend(BackendBase):
                     active: np.ndarray, page_table: np.ndarray,
                     rid: np.ndarray, temperature: np.ndarray,
                     sample_idx: np.ndarray, key: jax.Array) -> np.ndarray:
-        if self._dirty:
-            # copies: on the CPU `jnp.asarray` may alias the engine's host
-            # arrays, which it updates in place between steps, and a
-            # mirror must hold what was uploaded, as it does on the TPU
-            self._t_dev = jnp.array(t)
-            self._md_dev = jnp.array(self.m_done)
-            self._pt_dev = jnp.array(page_table)
-            self._ac_dev = jnp.array(active)
-            self._rid_dev = jnp.array(rid)
-            self._tp_dev = jnp.array(temperature)
-            self._si_dev = jnp.array(sample_idx)
-            self._dirty = False
-        # host mirror of the device-side due/m_done transition
-        w = self.window
-        due = active & (t % w == 0) & (t // w > self.m_done)
-        self.m_done = np.where(due, t // w, self.m_done)
+        n_active = int(np.count_nonzero(active))
+        with spans.span("backend.decode", slots=n_active):
+            if self._dirty:
+                self.mirror_uploads += 1
+                with spans.span("backend.upload"):
+                    # copies: on the CPU `jnp.asarray` may alias the
+                    # engine's host arrays, which it updates in place
+                    # between steps, and a mirror must hold what was
+                    # uploaded, as it does on the TPU
+                    self._t_dev = jnp.array(t)
+                    self._md_dev = jnp.array(self.m_done)
+                    self._pt_dev = jnp.array(page_table)
+                    self._ac_dev = jnp.array(active)
+                    self._rid_dev = jnp.array(rid)
+                    self._tp_dev = jnp.array(temperature)
+                    self._si_dev = jnp.array(sample_idx)
+                self._dirty = False
+            # host mirror of the device-side due/m_done transition
+            w = self.window
+            due = active & (t % w == 0) & (t // w > self.m_done)
+            self.m_done = np.where(due, t // w, self.m_done)
 
-        out, self.states, self._t_dev, self._md_dev, self._si_dev = \
-            self._decode(self.params, self.states, jnp.asarray(tokens_in),
-                         self._t_dev, self._md_dev, self._pt_dev,
-                         self._ac_dev, self._rid_dev, self._si_dev,
-                         self._tp_dev, key)
-        self.decode_dispatches += 1
-        # fused sampling downloads [S] int32 tokens; the host path the
-        # whole [S, V] logits (docs/serving.md, host-transfer budget)
-        return np.asarray(out)
+            out, self.states, self._t_dev, self._md_dev, self._si_dev = \
+                self._decode(self.params, self.states,
+                             jnp.asarray(tokens_in), self._t_dev,
+                             self._md_dev, self._pt_dev, self._ac_dev,
+                             self._rid_dev, self._si_dev, self._tp_dev, key)
+            self._count_decode(n_active)
+            # fused sampling downloads [S] int32 tokens; the host path the
+            # whole [S, V] logits (docs/serving.md, host-transfer budget)
+            return spans.download(out)
 
     # -------------------------------------------------------- speculation --
 
@@ -447,8 +466,8 @@ class MiTABackend(BackendBase):
             jnp.asarray(t), jnp.asarray(ac), jnp.asarray(m_cnt),
             jnp.asarray(rid), jnp.asarray(sample_idx),
             jnp.asarray(temperature), key)
-        self.decode_dispatches += 1
-        return np.asarray(drafts)
+        self._count_decode(np.count_nonzero(ac))
+        return spans.download(drafts)
 
     def verify_step(self, tokens_in: np.ndarray, t: np.ndarray,
                     active: np.ndarray, page_table: np.ndarray,
@@ -475,8 +494,8 @@ class MiTABackend(BackendBase):
             jnp.asarray(active), jnp.asarray(rid),
             jnp.asarray(sample_idx), jnp.asarray(temperature), key,
             jnp.asarray(spec_len))
-        self.decode_dispatches += 1
-        return np.asarray(toks_out)
+        self._count_decode(np.count_nonzero(active))
+        return spans.download(toks_out)
 
     def rollback(self, commits: np.ndarray, active: np.ndarray) -> None:
         commits = np.where(np.asarray(active), np.asarray(commits), 1)

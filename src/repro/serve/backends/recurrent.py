@@ -26,6 +26,10 @@ Program inventory (mirroring the paged backend's three-program shape):
     capacity (one dispatch per admission group), used when the engine runs
     unchunked.
 
+In the profiler's trace they are ``jit_recurrent_decode_step`` and
+``jit_recurrent_chunk_prefill``; speculation adds ``jit_recurrent_draft``
+and ``jit_recurrent_teacher_forced``.
+
 The static reference (`static_reference`) is a STRUCTURALLY different
 program — a time-major `lax.scan` of the full decode step over the prompt,
 then single-token decode — so engine==reference greedy parity checks the
@@ -48,6 +52,7 @@ from repro.core.mita_decode import window_aligned
 from repro.models import mamba2 as m2
 from repro.models import rglru as rg
 from repro.models import transformer as tfm
+from repro.serve import spans
 from repro.serve.backends import BackendBase, sample_host
 
 # family -> (init_states(cfg, n_slots, capacity), decode(p, st, tok, pos,
@@ -71,7 +76,7 @@ def _decode_fn(family: str, cfg, fused_sampling: bool) -> Callable:
     compiled code."""
     _, decode_raw, _ = _OPS[family]
 
-    def step(p, st, tok, t, ac, rid, si, temp, key):
+    def recurrent_decode_step(p, st, tok, t, ac, rid, si, temp, key):
         logits, st_new = decode_raw(p, st, tok, t, cfg)
         st = slotted.where_slots(ac, st_new, st, axis=1)
         adv = ac.astype(t.dtype)
@@ -81,7 +86,7 @@ def _decode_fn(family: str, cfg, fused_sampling: bool) -> Callable:
             out = logits
         return out, st, t + adv, si + adv
 
-    return jax.jit(step, donate_argnums=(1, 3, 6))
+    return jax.jit(recurrent_decode_step, donate_argnums=(1, 3, 6))
 
 
 @functools.lru_cache(maxsize=None)
@@ -91,12 +96,12 @@ def _chunk_fn(family: str, cfg) -> Callable:
     bit-identical).  Jit caches one program per (chunk length, row width)."""
     _, _, chunk_raw = _OPS[family]
 
-    def run(p, st, slot_ids, toks, t0, n_valid):
+    def recurrent_chunk_prefill(p, st, slot_ids, toks, t0, n_valid):
         sub = slotted.gather_slots(st, slot_ids)
         logits, sub = chunk_raw(p, sub, toks, t0, n_valid, cfg)
         return logits, slotted.scatter_slots(st, slot_ids, sub)
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(recurrent_chunk_prefill, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -110,7 +115,7 @@ def _spec_draft_fn(family: str, cfg, n_pos: int) -> Callable:
     is dispatch collapse: one program commits up to ``n_pos`` tokens."""
     _, decode_raw, _ = _OPS[family]
 
-    def run(p, st, tok, t, ac, rid, si, temp, key, spec_len):
+    def recurrent_draft(p, st, tok, t, ac, rid, si, temp, key, spec_len):
         def body(carry, i):
             st, tok, t, si = carry
             ac_i = ac & (i < spec_len)
@@ -125,7 +130,7 @@ def _spec_draft_fn(family: str, cfg, n_pos: int) -> Callable:
                                              jnp.arange(n_pos))
         return drafts, st
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(recurrent_draft, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -141,7 +146,8 @@ def _spec_tf_fn(family: str, cfg, n_pos: int) -> Callable:
     verify scan consumed exactly these inputs from the same state)."""
     _, decode_raw, _ = _OPS[family]
 
-    def run(p, st, toks, t, ac, rid, si, temp, key, n_steps):
+    def recurrent_teacher_forced(p, st, toks, t, ac, rid, si, temp, key,
+                                 n_steps):
         def body(carry, inp):
             st, t, si = carry
             i, tok = inp
@@ -156,7 +162,7 @@ def _spec_tf_fn(family: str, cfg, n_pos: int) -> Callable:
                                         (jnp.arange(n_pos), toks))
         return outs, st
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(recurrent_teacher_forced, donate_argnums=(1,))
 
 
 # snapshot for the stress path's rollback; scans donate their state input,
@@ -241,11 +247,12 @@ class _RecurrentBackend(BackendBase):
         nc = window_aligned(n, self.window)
         toks = np.zeros((k, nc), np.int32)
         toks[:, :n] = prompts
-        logits, self.states = _chunk_fn(self.family, self.cfg)(
-            self.params, self.states, jnp.asarray(slots, jnp.int32),
-            jnp.asarray(toks), jnp.zeros(k, jnp.int32),
-            jnp.full(k, n, jnp.int32))
-        return np.asarray(logits)
+        with spans.span("backend.prefill", rows=k, tokens=k * n):
+            logits, self.states = _chunk_fn(self.family, self.cfg)(
+                self.params, self.states, jnp.asarray(slots, jnp.int32),
+                jnp.asarray(toks), jnp.zeros(k, jnp.int32),
+                jnp.full(k, n, jnp.int32))
+            return spans.download(logits)
 
     def prefill_chunk(self, slot: int, pt_row: np.ndarray, toks: np.ndarray,
                       t0: int, n_valid: int, n_train: int) -> np.ndarray:
@@ -264,11 +271,15 @@ class _RecurrentBackend(BackendBase):
         #                                 recomputed generated positions are
         #                                 exact by construction
         nv = np.where(job_active, n_valid, 0).astype(np.int32)
-        logits, self.states = _chunk_fn(self.family, self.cfg)(
-            self.params, self.states, jnp.asarray(slot_ids, jnp.int32),
-            jnp.asarray(toks), jnp.asarray(t0, dtype=jnp.int32),
-            jnp.asarray(nv))
-        return np.asarray(logits)
+        valid = nv[np.asarray(job_active)]
+        with spans.span("backend.prefill", rows=len(valid),
+                        tokens=int(valid.sum())):
+            logits, self.states = _chunk_fn(self.family, self.cfg)(
+                self.params, self.states, jnp.asarray(slot_ids, jnp.int32),
+                jnp.asarray(toks), jnp.asarray(t0, dtype=jnp.int32),
+                jnp.asarray(nv))
+            self._count_prefill(valid)
+            return spans.download(logits)
 
     # ------------------------------------------------------------- decode --
 
@@ -277,21 +288,27 @@ class _RecurrentBackend(BackendBase):
                     rid: np.ndarray, temperature: np.ndarray,
                     sample_idx: np.ndarray, key: jax.Array) -> np.ndarray:
         del page_table                  # constant-size states: no pages
-        if self._dirty:
-            # copies: on the CPU `jnp.asarray` may alias the engine's host
-            # arrays, which it updates in place between steps, and a
-            # mirror must hold what was uploaded, as it does on the TPU
-            self._t_dev = jnp.array(t)
-            self._ac_dev = jnp.array(active)
-            self._rid_dev = jnp.array(rid)
-            self._tp_dev = jnp.array(temperature)
-            self._si_dev = jnp.array(sample_idx)
-            self._dirty = False
-        out, self.states, self._t_dev, self._si_dev = self._decode(
-            self.params, self.states, jnp.asarray(tokens_in), self._t_dev,
-            self._ac_dev, self._rid_dev, self._si_dev, self._tp_dev, key)
-        self.decode_dispatches += 1
-        return np.asarray(out)
+        n_active = int(np.count_nonzero(active))
+        with spans.span("backend.decode", slots=n_active):
+            if self._dirty:
+                self.mirror_uploads += 1
+                with spans.span("backend.upload"):
+                    # copies: on the CPU `jnp.asarray` may alias the
+                    # engine's host arrays, which it updates in place
+                    # between steps, and a mirror must hold what was
+                    # uploaded, as it does on the TPU
+                    self._t_dev = jnp.array(t)
+                    self._ac_dev = jnp.array(active)
+                    self._rid_dev = jnp.array(rid)
+                    self._tp_dev = jnp.array(temperature)
+                    self._si_dev = jnp.array(sample_idx)
+                self._dirty = False
+            out, self.states, self._t_dev, self._si_dev = self._decode(
+                self.params, self.states, jnp.asarray(tokens_in),
+                self._t_dev, self._ac_dev, self._rid_dev, self._si_dev,
+                self._tp_dev, key)
+            self._count_decode(n_active)
+            return spans.download(out)
 
     # -------------------------------------------------------- speculation --
 
@@ -316,8 +333,8 @@ class _RecurrentBackend(BackendBase):
             jnp.asarray(t), jnp.asarray(active), jnp.asarray(rid),
             jnp.asarray(sample_idx), jnp.asarray(temperature), key,
             jnp.asarray(spec_len))
-        self.decode_dispatches += 1
-        return np.asarray(drafts)
+        self._count_decode(np.count_nonzero(active))
+        return spans.download(drafts)
 
     def verify_step(self, tokens_in: np.ndarray, t: np.ndarray,
                     active: np.ndarray, page_table: np.ndarray,
@@ -351,8 +368,8 @@ class _RecurrentBackend(BackendBase):
                 jnp.asarray(t), jnp.asarray(active), jnp.asarray(rid),
                 jnp.asarray(sample_idx), jnp.asarray(temperature), key,
                 jnp.asarray(spec_len + 1))
-            self.decode_dispatches += 1
-            self._verify_toks = np.asarray(outs)
+            self._count_decode(np.count_nonzero(active))
+            self._verify_toks = spans.download(outs)
             return self._verify_toks
         # self mode: the draft scan already ran the exact decode rule and
         # committed its state, so the drafts verify themselves; one more
@@ -387,7 +404,7 @@ class _RecurrentBackend(BackendBase):
             jnp.asarray(t), jnp.asarray(active), jnp.asarray(rid),
             jnp.asarray(sample_idx), jnp.asarray(temp), key,
             jnp.asarray(n, jnp.int32))
-        self.decode_dispatches += 1
+        self._count_decode(np.count_nonzero(active))
         self._snap = self._verify_toks = self._stress = None
 
     # ------------------------------------------------------------- oracle --

@@ -52,6 +52,7 @@ import jax
 import numpy as np
 
 from repro.serve import backends as _backends
+from repro.serve import spans
 
 
 class AllocatorInvariantError(RuntimeError):
@@ -746,11 +747,11 @@ class ServingEngine:
 
     # ----------------------------------------------------------- admission --
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> list[int]:
+        """Admit what fits; returns the admitted request ids."""
         if self.ecfg.prefill_chunk:
-            self._admit_chunked(now)
-        else:
-            self._admit_grouped(now)
+            return self._admit_chunked(now)
+        return self._admit_grouped(now)
 
     def _entry_total(self, entry: _WaitEntry) -> int:
         """Tokens the prefill of this entry must pack: the prompt, plus
@@ -804,7 +805,7 @@ class ServingEngine:
         first = min(self.ecfg.prefill_chunk, self._entry_total(entry) - t0)
         return self.backend.pages_needed(t0 + first) - shared_pages
 
-    def _admit_chunked(self, now: float) -> None:
+    def _admit_chunked(self, now: float) -> list[int]:
         """Chunked admission: one request at a time, first-chunk pages only.
         A higher-priority arrival preempts the lowest strictly-lower victim
         when slots or pages run short (invariant 2 becomes priority-ordered
@@ -813,6 +814,7 @@ class ServingEngine:
         reference (one retained ref per page), the backend installs the
         cached per-window summary rows, and the prefill job starts at the
         first unshared chunk instead of zero."""
+        admitted = []
         while self.waiting:
             entry = self.waiting[0]
             nodes = self._match_prefix(entry)
@@ -824,8 +826,9 @@ class ServingEngine:
                 nodes = self._match_prefix(entry)
                 first = self._first_chunk_pages(entry, len(nodes))
                 if not self.free_slots or not self.alloc.can_alloc(first):
-                    return
+                    break
             self.waiting.pop(0)
+            admitted.append(entry.req.rid)
             slot = self.free_slots.pop()
             if entry.resume is None:
                 toks = np.asarray(entry.req.prompt, np.int32)
@@ -865,8 +868,9 @@ class ServingEngine:
             self.backend.invalidate()
             self._seq += 1
             self.slot_seq[slot] = self._seq
+        return admitted
 
-    def _admit_grouped(self, now: float) -> None:
+    def _admit_grouped(self, now: float) -> list[int]:
         """Monolithic admission (``prefill_chunk`` = 0): priority-then-FCFS
         with same-length grouping — the head-of-line request picks the
         prompt length; other waiting requests of that length ride along in
@@ -875,10 +879,11 @@ class ServingEngine:
         on pages is deliberate — big requests are not starved by later
         small ones.  The full page budget is claimed up front (invariant
         3), so this path never needs preemption."""
+        admitted = []
         while self.waiting and self.free_slots:
             head = self.waiting[0].req
             if not self.alloc.can_alloc(self.pages_needed(head)):
-                return
+                break
             n = len(head.prompt)
             budget = (len(self.alloc.free) - self.alloc.reserve
                       - self.pages_needed(head))
@@ -921,6 +926,7 @@ class ServingEngine:
                     self._enqueue(e)
                 raise
 
+            admitted += [e.req.rid for e in group]
             for i, (entry, slot, pages) in enumerate(
                     zip(group, slots, pages_list)):
                 req = entry.req
@@ -947,6 +953,7 @@ class ServingEngine:
                 if req.max_new_tokens == 1:
                     self._retire(slot, time.perf_counter())
             self.backend.invalidate()
+        return admitted
 
     # ------------------------------------------------------ chunked prefill --
 
@@ -994,10 +1001,11 @@ class ServingEngine:
         best-keyed job advances, in its own dispatch."""
         if not self.prefilling:
             return
-        if self.ecfg.prefill_mode == "batched":
-            self._advance_prefill_batched(now)
-        else:
-            self._advance_prefill_per_job(now)
+        with spans.span("engine.prefill", step=self.steps):
+            if self.ecfg.prefill_mode == "batched":
+                self._advance_prefill_batched(now)
+            else:
+                self._advance_prefill_per_job(now)
 
     def _advance_prefill_batched(self, now: float) -> None:
         """One dispatch advances EVERY prefilling job one chunk.  Jobs that
@@ -1249,47 +1257,57 @@ class ServingEngine:
         """One engine iteration: retire/admit, advance at most one prefill
         chunk, then one fused decode step — or, with ``spec_k`` > 0, one
         speculative draft/verify/commit round — for the active batch.
-        Returns False when there is nothing left to do."""
-        self._expire_deadlines()
-        now = time.perf_counter()
-        self._admit(now)
-        self._advance_prefill(now)
-        if self.ecfg.prefill_chunk:
-            self._ensure_append_pages()
-        if not self.active.any():
-            return bool(self.waiting or self.prefilling)
+        Returns False when there is nothing left to do.  Each phase is a
+        host span (`serve.spans`) under the step's ``engine.step``."""
+        step = self.steps
+        with spans.step_span(step):
+            with spans.span("engine.admit", step=step) as sp:
+                self._expire_deadlines()
+                now = time.perf_counter()
+                admitted = self._admit(now)
+                if admitted and sp.is_enabled():
+                    sp.set_metadata(rid=" ".join(map(str, admitted)))
+            self._advance_prefill(now)
+            if self.ecfg.prefill_chunk:
+                with spans.span("engine.pages", step=step):
+                    self._ensure_append_pages()
+            if not self.active.any():
+                return bool(self.waiting or self.prefilling)
 
-        if self.ecfg.spec_k:
+            if self.ecfg.spec_k:
+                t0 = time.perf_counter()
+                with spans.span("engine.spec", step=step):
+                    self._spec_round(time.perf_counter())
+                self.step_times.append(time.perf_counter() - t0)
+                self.steps += 1
+                return True
+
+            fused_sampling = self.ecfg.sample_device == "fused"
             t0 = time.perf_counter()
-            self._spec_round(time.perf_counter())
+            # fused sampling downloads [S] int32 tokens; the host path the
+            # whole [S, V] logits (docs/serving.md, host-transfer budget)
+            out = self.backend.decode_step(
+                self.tokens_in, self.t, self.active, self.page_table,
+                self.slot_rid, self.slot_temp, self.sample_idx, self._key)
             self.step_times.append(time.perf_counter() - t0)
             self.steps += 1
+
+            now = time.perf_counter()
+            with spans.span("engine.emit", step=step):
+                for slot in np.nonzero(self.active)[0]:
+                    req = self.slot_req[slot]
+                    if fused_sampling:
+                        tok = int(out[slot])
+                    else:
+                        tok = self._sample(out[slot], req,
+                                           len(self.slot_out[slot]))
+                    self._emit(slot, tok, now)
+                    self.t[slot] += 1
+                    self.sample_idx[slot] += 1
+                    self.tokens_in[slot] = tok
+                    if len(self.slot_out[slot]) >= req.max_new_tokens:
+                        self._retire(slot, now)
             return True
-
-        fused_sampling = self.ecfg.sample_device == "fused"
-        t0 = time.perf_counter()
-        # fused sampling downloads [S] int32 tokens; the host path the
-        # whole [S, V] logits (docs/serving.md, host-transfer budget)
-        out = self.backend.decode_step(
-            self.tokens_in, self.t, self.active, self.page_table,
-            self.slot_rid, self.slot_temp, self.sample_idx, self._key)
-        self.step_times.append(time.perf_counter() - t0)
-        self.steps += 1
-
-        now = time.perf_counter()
-        for slot in np.nonzero(self.active)[0]:
-            req = self.slot_req[slot]
-            if fused_sampling:
-                tok = int(out[slot])
-            else:
-                tok = self._sample(out[slot], req, len(self.slot_out[slot]))
-            self._emit(slot, tok, now)
-            self.t[slot] += 1
-            self.sample_idx[slot] += 1
-            self.tokens_in[slot] = tok
-            if len(self.slot_out[slot]) >= req.max_new_tokens:
-                self._retire(slot, now)
-        return True
 
     def run(self, requests: list[Request],
             realtime: bool = False) -> list[FinishedRequest]:

@@ -62,6 +62,7 @@ from typing import Any, Optional
 import numpy as np
 
 from repro.distributed.fault_tolerance import StepTimer
+from repro.serve import spans
 from repro.serve.engine import (AllocatorInvariantError, FinishedRequest,
                                 Request, ServingEngine, _WaitEntry)
 
@@ -222,7 +223,8 @@ class Supervisor:
                 self.cfg.backoff_base_s * (2 ** (self._consecutive - 1)),
                 self.cfg.backoff_cap_s)
             if delay > 0:
-                time.sleep(delay)
+                with spans.span("supervisor.backoff", retry=self._consecutive):
+                    time.sleep(delay)
             return
         self._consecutive = 0
         slots = sorted({int(s) for s in getattr(e, "slots", []) or []})

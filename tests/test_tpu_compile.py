@@ -175,3 +175,35 @@ def test_head_dim_64_pool_needs_lane_padding(one_chip):
     with pytest.raises(Exception, match="aligned to tiling"):
         _compile_paged(one_chip, **a, lanes=a["d"])
 
+
+
+# every static keyword of the three kernels' jits
+STATIC = ("window", "n_route", "fuse_append", "pipeline", "vmem_limit",
+          "interpret", "k_width", "external_finalize", "q_block")
+
+
+def _another_name(jitted):
+    """The kernel's Python function under a jit named ``another_name``."""
+    def another_name(*args, **static):
+        return jitted.__wrapped__(*args, **static)
+    return jax.jit(another_name, static_argnames=STATIC)
+
+
+@pytest.mark.parametrize("module,name,compile_", [
+    (mpa, "mita_paged_attention",
+     lambda dev: _compile_paged(dev, **ARCHS["qwen3-0.6b"])),
+    (mpf, "mita_paged_finalize_fused",
+     lambda dev: _compile_finalize(dev, 8, 128)),
+    (mcp, "mita_chunk_prefill_fused",
+     lambda dev: _compile_chunk(dev, **ARCHS["qwen3-0.6b"]))])
+def test_kernel_name_is_pinned(one_chip, monkeypatch, module, name,
+                               compile_):
+    """Each Pallas call carries its own ``name``: compiled under a wrapper
+    of another name, the kernel's instruction keeps the name the
+    profiler's trace and the benchmark's readers look for."""
+    monkeypatch.setattr(module, name, _another_name(getattr(module, name)))
+    calls = [line.split(" = ")[0].split()[-1].lstrip("%")
+             for line in compile_(one_chip).as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert all(c.startswith(f"{name}.") for c in calls), calls
