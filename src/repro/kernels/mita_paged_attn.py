@@ -30,11 +30,29 @@ TPU (`kernels.ops.pool_lanes`): Mosaic DMAs one KV head's row of a
 ``[R, Hkv, L]`` pool only then; the kernels read its first ``d`` lanes.
 
 Per-program VMEM working set (budget-checked by `kernels.ops` before
-dispatch): q/out `2·G·d`, landmark tiles `2·M·d`, local page `2·w·d`, one
-expert KV tile `2·K·d`, plus the `M·K` expert bias table.  The
-expert-row gathers are double-buffered by default (row i+1's copies are in
-flight while row i's drain — the decode step is DMA-latency bound, not
-bandwidth bound); ``REPRO_DMA_PIPELINE=0`` serializes them for debugging
+dispatch): q/out `2·G·d`, landmark tiles `2·M·d`, local page `2·w·d`, two
+expert KV tile buffers `4·K·d`, plus the `M·K` expert bias table; in SMEM
+the `s·G` tiles' expert ordinals and two experts' row ids `2·K`.
+
+Issue order.  The walk is bound by DMA latency, not bytes (one 512-byte
+row per copy), so it keeps as many copies in flight as it can:
+
+  1. the new row's reads, the fused append and the local page's K and V
+     copies start together, then are awaited together;
+  2. routing runs for all ``s`` passes first: the walk is one flat
+     sequence of ``s·G`` expert tiles (route j of query head gi is tile
+     ``j·G + gi``), a tile with no walk (inactive slot, or no visible
+     landmark to route to) marked in SMEM and skipped under `pl.when`;
+  3. tile i+1's row ids are already in SMEM when tile i is awaited: every
+     one of its K rows' K and V copies starts into the other tile buffer
+     (``ROW_ISSUE_UNROLL`` rows per issue-loop iteration), then tile i+2's
+     ids start, then tile i is awaited — one wait per buffer and pool for
+     all its rows' bytes — and its `q·Kᵀ`, softmax and `p·V` run while
+     tile i+1 lands.
+
+The routed partials merge per route in the same order as the serial walk,
+so outputs do not depend on the issue order; ``REPRO_DMA_PIPELINE=0``
+walks each tile serially, one row's copy at a time, for debugging
 (`tests/test_kernel_oracle.py` pins parity in both modes).
 """
 
@@ -49,6 +67,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+# routed-row copies started per iteration of a tile's issue loop (Pallas
+# TPU unrolls a loop only whole, so this is done by hand)
+ROW_ISSUE_UNROLL = 8
 
 
 def _merge(m_a, l_a, o_a, m_b, l_b, o_b):
@@ -89,27 +110,26 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
     # inactive slots append to the trailing scratch row (never read back)
     row_new = jnp.where(act, page0 + ts % w, n_rows - 1)
 
-    # the new row HBM->VMEM (register patch below) and, when fused,
-    # HBM->HBM straight into its pool row
-    for src, dst in ((kn_hbm, knew), (vn_hbm, vnew)):
-        cp = pltpu.make_async_copy(src.at[s, pl.ds(h, 1)], dst, sem)
-        cp.start()
-        cp.wait()
+    # the new row HBM->VMEM (register patch below), the fused append
+    # HBM->HBM straight into its pool row, and the local page HBM->VMEM in
+    # token order, all in flight together; the appended position is patched
+    # from registers, so the result never depends on append/read ordering
+    head = pl.ds(h, 1)
+    copies = [pltpu.make_async_copy(kn_hbm.at[s, head], knew, sem),
+              pltpu.make_async_copy(vn_hbm.at[s, head], vnew, sem),
+              pltpu.make_async_copy(kpool_ref.at[pl.ds(page0, w), h], kloc,
+                                    sem),
+              pltpu.make_async_copy(vpool_ref.at[pl.ds(page0, w), h], vloc,
+                                    sem)]
     if fuse_append:
-        for src, dst in ((kn_hbm, kpout_ref), (vn_hbm, vpout_ref)):
-            cp = pltpu.make_async_copy(src.at[s, pl.ds(h, 1)],
-                                       dst.at[row_new, pl.ds(h, 1)], sem)
-            cp.start()
-            cp.wait()
-
-    # local page HBM->VMEM in token order; the appended position is patched
-    # from registers so the result never depends on append/read ordering
-    cp = pltpu.make_async_copy(kpool_ref.at[pl.ds(page0, w), h], kloc, sem)
-    cp.start()
-    cp.wait()
-    cp = pltpu.make_async_copy(vpool_ref.at[pl.ds(page0, w), h], vloc, sem)
-    cp.start()
-    cp.wait()
+        copies += [pltpu.make_async_copy(src.at[s, head],
+                                         dst.at[row_new, head], sem)
+                   for src, dst in ((kn_hbm, kpout_ref),
+                                    (vn_hbm, vpout_ref))]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
     q = q_ref[0, 0].astype(jnp.float32) * scale              # [G, d]
     g, d = q.shape
     # pool rows are lane-padded past the head dim (`ops.pool_lanes`)
@@ -120,7 +140,7 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
                       vloc[...].astype(jnp.float32))[:, :d]
 
     m_slot = lmq_ref.shape[2]
-    k_width = ketile.shape[0]
+    k_width = ketile.shape[1]
 
     # shared-landmark branch; r doubles as the routing logits
     lmq = lmq_ref[0, 0].astype(jnp.float32)                  # [M, d]
@@ -146,82 +166,138 @@ def _paged_kernel(pt_ref, t_ref, act_ref, mcnt_ref,              # SMEM
     # routed experts: top-s of r per query head (first index of the max,
     # the lax.top_k tie rule), expert rows gathered from the pool by their
     # stored GLOBAL row ids — no page-table lookup needed.  DMA addresses
-    # must be scalars: the expert ordinal goes vector -> SMEM through a
-    # full reduction, and the expert's K row ids are DMA'd HBM -> SMEM.
+    # must be scalars: each tile's expert ordinal goes vector -> SMEM
+    # through a full reduction (-1: no walk, for an inactive slot or a
+    # route with no visible landmark, whose output is masked anyway), and
+    # the expert's K row ids are DMA'd HBM -> SMEM.  Tile i = j·G + gi is
+    # route j of query head gi.
     g_ids = jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0)
     r_route = r
-    for _ in range(n_route):
+    oks = []
+    for j in range(n_route):
         mx = jnp.max(r_route, axis=-1, keepdims=True)        # [G, 1]
         e_j = jnp.min(jnp.where(r_route == mx, lm_ids, m_slot), axis=-1,
                       keepdims=True)                         # [G, 1]
         ok_j = mx > NEG_INF / 2
         r_route = jnp.where(lm_ids == e_j, NEG_INF, r_route)
+        e_live = jnp.where(ok_j, e_j, -1)
         for gi in range(g):
-            e_sm[gi] = jnp.sum(jnp.where(g_ids == gi, e_j, 0))
+            e_sm[j * g + gi] = jnp.where(
+                act, jnp.sum(jnp.where(g_ids == gi, e_live, 0)), -1)
+        oks.append(ok_j)
 
-        m_rows, l_rows, o_rows = [], [], []
-        for gi in range(g):
-            e_gi = jnp.minimum(e_sm[gi], m_slot - 1)
-            cp = pltpu.make_async_copy(ei_hbm.at[s, h, e_gi], rows_sm, sem)
+    n_tiles = n_route * g
+
+    def live(i):
+        return e_sm[i] >= 0
+
+    def expert(i):
+        return jnp.minimum(jnp.maximum(e_sm[i], 0), m_slot - 1)
+
+    def ids_copy(i):
+        # one expert's row ids HBM -> SMEM; at most one is in flight
+        return pltpu.make_async_copy(ei_hbm.at[s, h, expert(i)],
+                                     rows_sm.at[i % 2], sem)
+
+    def row_copies(i, kk):
+        b = i % 2
+        row = rows_sm[b, kk]
+        return (pltpu.make_async_copy(kpool_ref.at[pl.ds(row, 1), h],
+                                      ketile.at[b, pl.ds(kk, 1)],
+                                      psem.at[b, 0]),
+                pltpu.make_async_copy(vpool_ref.at[pl.ds(row, 1), h],
+                                      vetile.at[b, pl.ds(kk, 1)],
+                                      psem.at[b, 1]))
+
+    def start_rows(i):
+        # every row of the tile in flight before any is awaited
+        unroll = math.gcd(k_width, ROW_ISSUE_UNROLL)
+
+        def issue(kb, carry):
+            for u in range(unroll):
+                for cp in row_copies(i, kb * unroll + u):
+                    cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, k_width // unroll, issue, 0)
+
+    def wait_rows(i):
+        # one wait per tile buffer and pool, for all its rows' bytes
+        b = i % 2
+        for pool, tile, c in ((kpool_ref, ketile, 0), (vpool_ref, vetile, 1)):
+            pltpu.make_async_copy(pool.at[pl.ds(0, k_width), h], tile.at[b],
+                                  psem.at[b, c]).wait()
+
+    def serial_walk(i):
+        cp = ids_copy(i)
+        cp.start()
+        cp.wait()
+
+        def walk(kk, carry):
+            for cp in row_copies(i, kk):
+                cp.start()
+                cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, k_width, walk, 0)
+
+    if pipeline:
+        # tile 0's ids and rows, and tile 1's ids, ahead of the loop
+        @pl.when(live(0))
+        def _():
+            cp = ids_copy(0)
             cp.start()
             cp.wait()
-            bias = eb_ref[0, 0, pl.ds(e_gi, 1), :]           # [1, K]
+            start_rows(0)
 
-            def row_copies(kk, slot):
-                row = rows_sm[kk]
-                return (pltpu.make_async_copy(kpool_ref.at[pl.ds(row, 1), h],
-                                              ketile.at[pl.ds(kk, 1)],
-                                              psem.at[slot, 0]),
-                        pltpu.make_async_copy(vpool_ref.at[pl.ds(row, 1), h],
-                                              vetile.at[pl.ds(kk, 1)],
-                                              psem.at[slot, 1]))
+        if n_tiles > 1:
+            @pl.when(live(1))
+            def _():
+                ids_copy(1).start()
 
-            if pipeline:
-                # double-buffered row walk: row kk+1's copies are in
-                # flight while row kk's drain (distinct destination rows,
-                # alternating semaphore pairs) — hides the per-row DMA
-                # latency the serial walk pays K times
-                ck, cv = row_copies(0, 0)
-                ck.start()
-                cv.start()
+    m_rows, l_rows, o_rows = [], [], []
+    for i in range(n_tiles):
+        j, gi = divmod(i, g)
+        if pipeline:
+            # tile i+1's rows into the other buffer, and tile i+2's ids,
+            # go in flight before tile i is awaited and computed
+            if i + 1 < n_tiles:
+                @pl.when(live(i + 1))
+                def _():
+                    ids_copy(i + 1).wait()
+                    start_rows(i + 1)
 
-                def gather_row(kk, _):
-                    @pl.when(kk + 1 < k_width)
-                    def _():
-                        nk, nv = row_copies(kk + 1, (kk + 1) % 2)
-                        nk.start()
-                        nv.start()
-                    wk, wv = row_copies(kk, kk % 2)
-                    wk.wait()
-                    wv.wait()
-                    return 0
-            else:
-                def gather_row(kk, _):
-                    ck, cv = row_copies(kk, 0)
-                    ck.start()
-                    ck.wait()
-                    cv.start()
-                    cv.wait()
-                    return 0
+            if i + 2 < n_tiles:
+                @pl.when(live(i + 2))
+                def _():
+                    ids_copy(i + 2).start()
 
-            jax.lax.fori_loop(0, k_width, gather_row, 0)
-            s_e = jax.lax.dot_general(
-                q[gi:gi + 1], ketile[...].astype(jnp.float32)[:, :d],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [1, K]
-            s_e = s_e + bias
-            s_e = jnp.where(ok_j[gi:gi + 1], s_e, NEG_INF)
-            m_e, l_e, p_e = _partial(s_e)
-            o_e = jax.lax.dot_general(p_e,
-                                      vetile[...].astype(jnp.float32)[:, :d],
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            m_rows.append(m_e)
-            l_rows.append(l_e)
-            o_rows.append(o_e)
-        m_acc, l_acc, o_acc = _merge(
-            m_acc, l_acc, o_acc, jnp.concatenate(m_rows),
-            jnp.concatenate(l_rows), jnp.concatenate(o_rows))
+            pl.when(live(i))(lambda: wait_rows(i))
+        else:
+            pl.when(live(i))(lambda: serial_walk(i))
+
+        b = i % 2
+        bias = eb_ref[0, 0, pl.ds(expert(i), 1), :]         # [1, K]
+        s_e = jax.lax.dot_general(
+            q[gi:gi + 1], ketile[b].astype(jnp.float32)[:, :d],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [1, K]
+        s_e = s_e + bias
+        s_e = jnp.where(oks[j][gi:gi + 1], s_e, NEG_INF)
+        m_e, l_e, p_e = _partial(s_e)
+        o_e = jax.lax.dot_general(p_e, vetile[b].astype(jnp.float32)[:, :d],
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        # a tile that was not walked holds stale rows, maybe not finite
+        o_e = jnp.where(live(i), o_e, 0.0)
+        m_rows.append(m_e)
+        l_rows.append(l_e)
+        o_rows.append(o_e)
+        if gi == g - 1:
+            m_acc, l_acc, o_acc = _merge(
+                m_acc, l_acc, o_acc, jnp.concatenate(m_rows),
+                jnp.concatenate(l_rows), jnp.concatenate(o_rows))
+            m_rows, l_rows, o_rows = [], [], []
 
     denom = jnp.where(l_acc == 0.0, 1.0, l_acc)
     out = o_acc / denom[:, None]
@@ -290,12 +366,12 @@ def mita_paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             pltpu.VMEM((1, lanes), v_pool.dtype),
             pltpu.VMEM((window, lanes), k_pool.dtype),
             pltpu.VMEM((window, lanes), v_pool.dtype),
-            pltpu.VMEM((k_width, lanes), k_pool.dtype),
-            pltpu.VMEM((k_width, lanes), v_pool.dtype),
-            pltpu.SMEM((g,), jnp.int32),           # routed expert ordinals
-            pltpu.SMEM((k_width,), jnp.int32),     # one expert's row ids
+            pltpu.VMEM((2, k_width, lanes), k_pool.dtype),
+            pltpu.VMEM((2, k_width, lanes), v_pool.dtype),
+            pltpu.SMEM((n_route * g,), jnp.int32),  # each tile's expert
+            pltpu.SMEM((2, k_width), jnp.int32),    # two experts' row ids
             pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA((2, 2)),   # expert-row pipeline pairs
+            pltpu.SemaphoreType.DMA((2, 2)),   # (tile buffer, K/V)
         ],
     )
     kern = functools.partial(_paged_kernel, window=window, n_route=n_route,

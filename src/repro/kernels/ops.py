@@ -187,12 +187,13 @@ def scatter_pool_rows(pool: jax.Array, rows: jax.Array,
 def paged_attention_vmem_bytes(window: int, m: int, k_width: int, g: int,
                                d: int, itemsize: int = 4) -> int:
     """Per-program VMEM working set of the fused paged-decode kernel:
-    q + out, the landmark tiles, the local page, one expert KV tile, and
-    the expert index/bias tables (`kernels.mita_paged_attn` docstring)."""
+    q + out, the landmark tiles, the local page, two expert KV tiles (one
+    computed while the next is gathered), and the expert index/bias
+    tables (`kernels.mita_paged_attn` docstring)."""
     tiles = (2 * g * d          # q + out
              + 2 * m * d        # lm_q + lm_v
              + 2 * window * d   # local page (k, v)
-             + 2 * k_width * d)  # expert KV tile scratch
+             + 4 * k_width * d)  # two expert KV tile buffers
     tables = m * k_width * (4 + 4)   # expert_idx (i32) + bias (f32)
     return tiles * itemsize + tables
 
@@ -275,9 +276,11 @@ def paged_decode_attend(q, k_new, v_new, lm_q, lm_v, expert_idx,
 
 
 def dma_pipeline() -> bool:
-    """REPRO_DMA_PIPELINE: double-buffer the paged-decode kernel's per-row
-    routed-expert DMAs (prefetch row i+1 while row i's copy drains).
-    Default on; set to 0 to serialize the copies (debug / parity bisect)."""
+    """REPRO_DMA_PIPELINE: the paged-decode kernel's routed-expert walk
+    keeps every row of an expert tile in flight, and gathers the next
+    tile into a second buffer while one is computed (issue order in the
+    `kernels.mita_paged_attn` docstring).  Default on; set to 0 to walk
+    each tile one row's copy at a time (debug / parity bisect)."""
     return os.environ.get("REPRO_DMA_PIPELINE", "1") != "0"
 
 

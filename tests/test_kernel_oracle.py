@@ -158,10 +158,15 @@ def _paged_pair(s_route=1, external=True, impl="kernel"):
     return cfg_x, dataclasses.replace(cfg_x, paged_impl=impl)
 
 
-def _drive(cfg_x, cfg_k, offs, n_steps, seed=3, b=3, hkv=2, g=2, d=16):
+def _drive(cfg_x, cfg_k, offs, n_steps, seed=3, hkv=2, g=2, d=16,
+           record=None):
     """Step the XLA oracle and the kernel side by side over a shuffled page
-    pool with per-slot staggered activity; assert outputs AND pools match
-    every step (the pools pin the fused scratch-row append)."""
+    pool with per-slot staggered activity (slot s joins at step offs[s]);
+    assert outputs AND pools match every step (the pools pin the fused
+    scratch-row append), that inactive slots output exact zeros, and that
+    a step writes no pool row but the active slots' append rows and the
+    scratch row.  ``record`` collects the kernel's outputs."""
+    b = len(offs)
     key = jax.random.PRNGKey(seed)
     q = jax.random.normal(key, (b, hkv, g, n_steps, d))
     k, v = (jax.random.normal(kk, (b, hkv, n_steps, d))
@@ -190,10 +195,20 @@ def _drive(cfg_x, cfg_k, offs, n_steps, seed=3, b=3, hkv=2, g=2, d=16):
         ki = jnp.stack([k[s, :, (i - offs[s]) % n_steps] for s in range(b)])
         vi = jnp.stack([v[s, :, (i - offs[s]) % n_steps] for s in range(b)])
         td, ad = jnp.asarray(t), jnp.asarray(act)
+        before = np.asarray(st_k.k_pool)
         o_x, st_x = step_x(st_x, qi, ki, vi, page_table, td, ad)
         o_k, st_k = step_k(st_k, qi, ki, vi, page_table, td, ad)
         np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_x),
                                    atol=2e-5, err_msg=f"step {i}")
+        assert not np.asarray(o_k)[~act].any(), f"step {i}"
+        if record is not None:
+            record.append(np.asarray(o_k))
+        appends = {int(page_table[s, t[s] // W]) * W + int(t[s]) % W
+                   for s in range(b) if act[s]}
+        written = np.flatnonzero(
+            (np.asarray(st_k.k_pool) != before).any(axis=(1, 2)))
+        assert set(written) <= appends | {before.shape[0] - 1}, (
+            f"step {i}: pool rows {sorted(set(written) - appends)} written")
         np.testing.assert_array_equal(np.asarray(st_k.k_pool),
                                       np.asarray(st_x.k_pool),
                                       err_msg=f"k_pool step {i}")
@@ -204,16 +219,27 @@ def _drive(cfg_x, cfg_k, offs, n_steps, seed=3, b=3, hkv=2, g=2, d=16):
     return st_x, st_k
 
 
-@pytest.mark.parametrize("s_route,external", [(1, True), (2, True),
-                                              (1, False)])
-def test_paged_kernel_matches_xla_staggered(s_route, external):
+@pytest.mark.parametrize("s_route,external,g", [
+    pytest.param(1, True, 2, id="1-True"),
+    pytest.param(2, True, 2, id="2-True"),
+    pytest.param(1, False, 2, id="1-False"),
+    pytest.param(1, True, 8, id="1-True-g8"),
+    pytest.param(2, True, 8, id="2-True-g8")])
+def test_paged_kernel_matches_xla_staggered(s_route, external, g):
     """Kernel vs XLA gather path over shuffled pages, ragged per-slot t
     (slots join at different steps), inactive slots, inline + external
     finalize, and multi-expert routing.  Pools are compared bit-exactly —
     the kernel's fused append (external mode) must write exactly the rows
-    the XLA scatter writes, scratch row included."""
+    the XLA scatter writes, scratch row included.  The g8 cases give each
+    KV head 8 query heads, each routing on its own (one expert tile per
+    head and route, the next one gathered while one is computed), with a
+    slot that stays inactive (no walk, zero output, appends only to the
+    scratch row) and a slot that joins late and has no visible landmark
+    for its first window (no routed walk, local and shared branches
+    only)."""
     cfg_x, cfg_k = _paged_pair(s_route=s_route, external=external)
-    _drive(cfg_x, cfg_k, offs=[0, 5, 11], n_steps=24)
+    offs = [0, 5, 11] if g == 2 else [0, 5, 17, 24]
+    _drive(cfg_x, cfg_k, offs=offs, n_steps=24, g=g)
 
 
 def test_paged_kernel_scratch_row_append():
@@ -795,14 +821,20 @@ def test_fallback_counters_reset_and_scope():
 
 
 def test_paged_kernel_dma_pipeline_parity(monkeypatch):
-    """REPRO_DMA_PIPELINE=0 (serial expert-row DMAs) and =1 (double-
-    buffered) produce identical decode steps — the pipeline only reorders
-    copies into disjoint destination rows."""
+    """REPRO_DMA_PIPELINE=0 (serial expert-row DMAs) and =1 (whole tiles
+    in flight, the next one gathered while one is computed) produce
+    identical decode steps — the pipeline only reorders copies into
+    disjoint destination rows."""
     cfg_x, cfg_k = _paged_pair(s_route=2)
-    monkeypatch.setenv("REPRO_DMA_PIPELINE", "0")
-    _drive(cfg_x, cfg_k, offs=[0, 3, 7], n_steps=12)
-    monkeypatch.setenv("REPRO_DMA_PIPELINE", "1")
-    _drive(cfg_x, cfg_k, offs=[0, 3, 7], n_steps=12)
+    runs = {}
+    for mode in ("0", "1"):
+        monkeypatch.setenv("REPRO_DMA_PIPELINE", mode)
+        runs[mode] = []
+        _, st_k = _drive(cfg_x, cfg_k, offs=[0, 3, 7], n_steps=12,
+                         record=runs[mode])
+        runs[mode].append(np.asarray(st_k.k_pool))
+    for a, b in zip(runs["0"], runs["1"], strict=True):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_block_q_env_default(monkeypatch):

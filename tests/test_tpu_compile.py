@@ -9,10 +9,12 @@ compiled with the scoped-VMEM limit the dispatcher would give it, so
 these tests also hold the `kernels.ops` working-set estimators to what
 the compiler needs.
 
-Widths: qwen3-0.6b (8 KV heads, 2 query heads per group, head dim 128)
-and tinyllama-1.1b (4 KV heads, 8 per group, head dim 64), MiTA window
-and expert width 128, 4 slots; f32 pools whose head rows are whole
-128-lane tiles (`ops.pool_lanes`).
+Widths: qwen3-0.6b (8 KV heads, 2 query heads per group, head dim 128),
+tinyllama-1.1b (4 KV heads, 8 per group, head dim 64) and qwen3-32b-pp16
+(8 KV heads, 8 per group, head dim 128; the benchmark's cells, whose
+slots hold 48 and 66 landmarks), MiTA window and expert width 128, 4
+slots; f32 pools whose head rows are whole 128-lane tiles
+(`ops.pool_lanes`).
 
 The topology is described inside a module fixture (never at import), and
 JAX's persistent compilation cache is off around these compiles — an
@@ -32,7 +34,8 @@ from repro.kernels import mita_paged_finalize as mpf
 from repro.kernels import ops
 
 ARCHS = {"qwen3-0.6b": dict(hkv=8, g=2, d=128),
-         "tinyllama-1.1b": dict(hkv=4, g=8, d=64)}
+         "tinyllama-1.1b": dict(hkv=4, g=8, d=64),
+         "qwen3-32b-pp16": dict(hkv=8, g=8, d=128)}
 W, K, SLOTS, NC = 128, 128, 4, 512
 
 
@@ -142,11 +145,13 @@ def _compile_chunk(dev, hkv, g, d, m=17):
         q_block=q_block, vmem_limit=limit).compile()
 
 
-@pytest.mark.parametrize("arch,n_route", [("qwen3-0.6b", 1),
-                                          ("qwen3-0.6b", 2),
-                                          ("tinyllama-1.1b", 1)])
-def test_paged_decode_kernel_compiles(one_chip, arch, n_route):
-    compiled = _compile_paged(one_chip, **ARCHS[arch], n_route=n_route)
+@pytest.mark.parametrize("arch,n_route,m", [
+    pytest.param("qwen3-0.6b", 1, 17, id="qwen3-0.6b-1"),
+    pytest.param("qwen3-0.6b", 2, 17, id="qwen3-0.6b-2"),
+    pytest.param("tinyllama-1.1b", 1, 17, id="tinyllama-1.1b-1"),
+    ("qwen3-32b-pp16", 1, 48), ("qwen3-32b-pp16", 1, 66)])
+def test_paged_decode_kernel_compiles(one_chip, arch, n_route, m):
+    compiled = _compile_paged(one_chip, **ARCHS[arch], m=m, n_route=n_route)
     assert "tpu_custom_call" in compiled.as_text()
 
 
