@@ -6,7 +6,9 @@ moves: the program's float32 pools, its 128-lane row padding and any
 later change to them all read against the same yardstick.  Each term is
 counted on its own:
 
-  * matmul parameters touched: 2 operations per parameter per token;
+  * matmul parameters touched: 2 operations per parameter per token (of
+    a routed mixture of experts, only the experts a token is routed to,
+    at the chip's share);
   * MiTA landmark scores: a closing window scores its landmark query
     against every key before its end, and takes the softmax-weighted sum
     of their values (per KV head);
@@ -17,33 +19,54 @@ Bytes are the least a kernel must move: what it reads once and writes
 once, with one routed expert per KV head (query heads of a group that
 route alike share its rows), so a roofline share from them cannot pass
 100% unless the kernel's time leaves out part of its work.
+
+What differs between architectures (the layers' matrix products, which
+layers are MiTA layers) is counted by the configuration's model module
+(`bench.model.module`): `token_flops`, `paged_decode` and `chunk_prefill`
+here hand each call on to it.  The MiTA attention terms below are one
+layer's and shared by every model module.
 """
 
 from __future__ import annotations
 
+from bench import model
+
 BF16 = 2
 
 
+def token_flops(spec: dict, pos: int, prompt: bool, head: bool) -> int:
+    """Model operations for one token through every layer (and the head
+    when its next token is sampled)."""
+    return model.module(spec).token_flops(spec, pos, prompt, head)
+
+
+def paged_decode(spec: dict, positions) -> tuple[int, int]:
+    """(operations, bytes) of the paged decode kernel for one decode step
+    over every layer, for the active slots at ``positions``."""
+    return model.module(spec).paged_decode(spec, positions)
+
+
+def chunk_prefill(spec: dict, rows) -> tuple[int, int]:
+    """(operations, bytes) of the chunk-prefill kernel for one dispatch
+    over every layer.  ``rows``: (resume point, tokens) per prefilled
+    row."""
+    return model.module(spec).chunk_prefill(spec, rows)
+
+
+# ---------------------------------------------- one MiTA layer's terms --
+
 def dims(spec: dict) -> dict:
     m = spec["mita"]
-    return {"d": spec["hidden_size"], "h": spec["num_attention_heads"],
+    return {"h": spec["num_attention_heads"],
             "kv": spec["num_key_value_heads"], "dh": spec["head_dim"],
-            "f": spec["intermediate_size"], "v": spec["vocab_size"],
-            "layers": spec["num_hidden_layers"], "w": m["window"],
-            "k": m["expert_width"]}
+            "w": m["window"], "k": m["expert_width"]}
 
 
-def layer_matmul_params(spec: dict) -> int:
-    """Parameters of one layer's matrix products."""
+def attn_matmul_params(spec: dict) -> int:
+    """Parameters of one layer's attention projections (q, k, v, o)."""
     c = dims(spec)
-    attn = c["d"] * (c["h"] + 2 * c["kv"]) * c["dh"] + c["h"] * c["dh"] \
-        * c["d"]
-    return attn + 3 * c["d"] * c["f"]
-
-
-def head_params(spec: dict) -> int:
-    c = dims(spec)
-    return c["d"] * c["v"]
+    d = spec["hidden_size"]
+    return d * (c["h"] + 2 * c["kv"]) * c["dh"] + c["h"] * c["dh"] * d
 
 
 def visible_landmarks(pos: int, w: int, prompt: bool) -> int:
@@ -71,24 +94,12 @@ def landmark_flops(spec: dict, pos: int) -> int:
     return c["kv"] * 4 * c["dh"] * (pos + 1)
 
 
-def token_flops(spec: dict, pos: int, prompt: bool, head: bool) -> int:
-    """Model operations for one token through every layer (and the head
-    when its next token is sampled)."""
-    c = dims(spec)
-    per_layer = (2 * layer_matmul_params(spec)
-                 + attend_flops(spec, pos, prompt)
-                 + landmark_flops(spec, pos))
-    return c["layers"] * per_layer + (2 * head_params(spec) if head else 0)
-
-
-# ------------------------------------------------------------- kernels --
-
-def paged_decode(spec: dict, positions) -> tuple[int, int]:
-    """(operations, bytes) of the paged decode kernel for one decode step
-    over every layer: attend + append for each active slot at its
-    position.  Bytes: the visible landmark queries and values, one
-    expert's k rows and the local window's rows (K and V) per KV head,
-    the query and output per query head, and the appended K and V row."""
+def paged_decode_layer(spec: dict, positions) -> tuple[int, int]:
+    """(operations, bytes) of the paged decode kernel in one layer:
+    attend + append for each active slot at its position.  Bytes: the
+    visible landmark queries and values, one expert's k rows and the
+    local window's rows (K and V) per KV head, the query and output per
+    query head, and the appended K and V row."""
     c = dims(spec)
     ops = nbytes = 0
     for p in positions:
@@ -98,13 +109,12 @@ def paged_decode(spec: dict, positions) -> tuple[int, int]:
         ops += attend_flops(spec, p, prompt=False)
         nbytes += BF16 * c["dh"] * (
             c["kv"] * (2 * a + 2 * rows + 2) + 2 * c["h"])
-    return c["layers"] * ops, c["layers"] * nbytes
+    return ops, nbytes
 
 
-def chunk_prefill(spec: dict, rows) -> tuple[int, int]:
-    """(operations, bytes) of the chunk-prefill kernel for one dispatch
-    over every layer.  ``rows``: (resume point, tokens) per prefilled
-    row.  Operations: every token's attention at prompt semantics and the
+def chunk_prefill_layer(spec: dict, rows) -> tuple[int, int]:
+    """(operations, bytes) of the chunk-prefill kernel in one layer.
+    Operations: every token's attention at prompt semantics and the
     landmarks of the windows the chunk closes.  Bytes: the context's K
     and V before the chunk read once (the closing windows score every
     earlier key), the chunk's K and V written, its queries read and
@@ -118,7 +128,7 @@ def chunk_prefill(spec: dict, rows) -> tuple[int, int]:
                 spec, p)
         nbytes += BF16 * c["dh"] * (2 * c["kv"] * (t0 + n)
                                     + 2 * c["h"] * n)
-    return c["layers"] * ops, c["layers"] * nbytes
+    return ops, nbytes
 
 
 def roofline_seconds(ops: int, nbytes: int, peak: dict) -> float:

@@ -2,15 +2,21 @@
 
 A configuration file (``bench/configs/<name>.json``) states the model's
 sizes under the keys of its published ``config.json``, the MiTA settings
-and the dtypes it is served in.  This module turns it into the program's
-`ModelConfig` and `EngineConfig`, and makes the weights from a seed on the
-device, in the program's parameter layout and the type they are served in.
-The weights belong to the benchmark: the plain reference reads the same
-arrays, and nothing the program makes.
+and the dtypes it is served in, and names two modules beside it: its
+plain reference (``"reference"``, `bench.check`) and its model module
+(``"model"``, `module`), which knows the architecture's keys, the
+program's `ModelConfig` and parameter layout, and its operation counts.
+This module turns the file into the program's `ModelConfig` and
+`EngineConfig` through that module, and makes the weights from a seed on
+the device, in the program's parameter layout and the type they are
+served in.  The weights belong to the benchmark: the plain reference reads
+the same arrays, and nothing the program makes.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
@@ -31,6 +37,9 @@ NORM_SPREAD = 0.1
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
+# the key under which a loaded configuration keeps its directory
+CONFIGS_DIR = "configs_dir"
+
 
 def load_json(kind: str, name: str, bench_dir: Path = BENCH) -> dict:
     """``bench/<kind>/<name>.json``."""
@@ -38,7 +47,10 @@ def load_json(kind: str, name: str, bench_dir: Path = BENCH) -> dict:
 
 
 def load_config(name: str, bench_dir: Path = BENCH) -> dict:
-    return load_json("configs", name, bench_dir)
+    """``bench/configs/<name>.json``, which remembers its directory: the
+    model module it names is looked up beside it."""
+    return {**load_json("configs", name, bench_dir),
+            CONFIGS_DIR: str(bench_dir / "configs")}
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -48,37 +60,58 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.PRNGKey(int(state[0]) >> 1)
 
 
+def module(spec: dict):
+    """The model module a configuration names under ``"model"``:
+    ``<module>.py`` in the directory the configuration was loaded from
+    (``bench/configs/`` by default).  It defines ``FAMILY`` (the registry
+    family `serve.backends.for_arch` dispatches on), ``REGISTRY_KEYS``
+    (published key -> `ModelConfig` attribute), ``model_config(spec)``,
+    ``param_shapes(spec)`` and the counts `bench.flops` hands on:
+    ``token_flops``, ``paged_decode`` and ``chunk_prefill``."""
+    if "model" not in spec:
+        raise ValueError(
+            f"configuration {spec.get('name')!r} names no model module: "
+            'give it "model": "<module>" for bench/configs/<module>.py')
+    path = f"{spec.get(CONFIGS_DIR, BENCH / 'configs')}/{spec['model']}.py"
+    try:
+        return _load_module(path)
+    except FileNotFoundError:
+        raise FileNotFoundError(
+            f"configuration {spec.get('name')!r} names the model module "
+            f"{spec['model']!r}, but {path} does not exist") from None
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: str):
+    """One module object per file (the counts are looked up per token)."""
+    mspec = importlib.util.spec_from_file_location(
+        f"bench_model_{Path(path).stem}", path)
+    mod = importlib.util.module_from_spec(mspec)
+    mspec.loader.exec_module(mod)
+    return mod
+
+
 def model_config(spec: dict):
     """The program's `ModelConfig` for a configuration file."""
-    from repro.models.modules import AttnConfig, ModelConfig
-
-    mita = spec["mita"]
-    return ModelConfig(
-        name=spec["name"],
-        n_layers=spec["num_hidden_layers"],
-        d_model=spec["hidden_size"],
-        n_heads=spec["num_attention_heads"],
-        n_kv=spec["num_key_value_heads"],
-        head_dim=spec["head_dim"],
-        d_ff=spec["intermediate_size"],
-        vocab=spec["vocab_size"],
-        rope_theta=float(spec["rope_theta"]),
-        qk_norm=True,
-        norm_eps=float(spec["rms_norm_eps"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        attn=AttnConfig(backend="mita", window=mita["window"],
-                        k=mita["expert_width"], s=mita["routed_experts"],
-                        vmem_budget=int(mita.get("vmem_budget_bytes", 0))),
-        param_dtype=DTYPES[spec["dtypes"]["params"]],
-        compute_dtype=DTYPES[spec["dtypes"]["compute"]],
-        remat=False)
+    return module(spec).model_config(spec)
 
 
 def arch_config(spec: dict):
     from repro.configs.registry import ArchConfig
 
-    return ArchConfig(arch_id=spec["name"], family="dense",
+    return ArchConfig(arch_id=spec["name"], family=module(spec).FAMILY,
                       model=model_config(spec))
+
+
+def attn_config(spec: dict):
+    """The program's MiTA `AttnConfig` from the configuration's ``mita``
+    settings."""
+    from repro.models.modules import AttnConfig
+
+    mita = spec["mita"]
+    return AttnConfig(backend="mita", window=mita["window"],
+                      k=mita["expert_width"], s=mita["routed_experts"],
+                      vmem_budget=int(mita.get("vmem_budget_bytes", 0)))
 
 
 def engine_config(slots: int, pages_per_slot: int, n_pages: int):
@@ -112,36 +145,10 @@ def prefill_widths(slots: int) -> list[int]:
 
 
 def param_shapes(spec: dict) -> dict:
-    """Name -> (shape, init scale) of every weight, in the program's
-    layout (layer weights stacked on axis 0)."""
-    d = spec["hidden_size"]
-    h, kv, dh = (spec["num_attention_heads"], spec["num_key_value_heads"],
-                 spec["head_dim"])
-    f, v, n = spec["intermediate_size"], spec["vocab_size"], \
-        spec["num_hidden_layers"]
-    dense = lambda din: 1.0 / np.sqrt(din)
-    # residual-branch outputs scaled by 1/sqrt(2 * layers), as GPT-2 and
-    # Megatron initialise them, so the random network stays well
-    # conditioned with depth
-    out = lambda din: dense(din) / np.sqrt(2 * n)
-    shapes = {
-        "emb.tok": ((v, d), 0.02),
-        "blocks.ln1": ((n, d), NORM_SPREAD),
-        "blocks.ln2": ((n, d), NORM_SPREAD),
-        "blocks.attn.wq": ((n, d, h * dh), dense(d)),
-        "blocks.attn.wk": ((n, d, kv * dh), dense(d)),
-        "blocks.attn.wv": ((n, d, kv * dh), dense(d)),
-        "blocks.attn.wo": ((n, h * dh, d), out(h * dh)),
-        "blocks.attn.q_norm": ((n, dh), NORM_SPREAD),
-        "blocks.attn.k_norm": ((n, dh), NORM_SPREAD),
-        "blocks.ffn.wi": ((n, d, f), dense(d)),
-        "blocks.ffn.wg": ((n, d, f), dense(d)),
-        "blocks.ffn.wo": ((n, f, d), out(f)),
-        "ln_f": ((d,), NORM_SPREAD),
-    }
-    if not spec["tie_word_embeddings"]:
-        shapes["emb.head"] = ((d, v), dense(d))
-    return shapes
+    """Name -> (shape, init scale[, dtype name]) of every weight, in the
+    program's layout; a weight with no dtype of its own is made in the
+    configuration's params dtype."""
+    return module(spec).param_shapes(spec)
 
 
 def init_params(spec: dict, key: jax.Array) -> dict:
@@ -153,13 +160,14 @@ def init_params(spec: dict, key: jax.Array) -> dict:
     shapes = param_shapes(spec)
     keys = jax.random.split(key, len(shapes))
     tree: dict = {}
-    for k, (name, (shape, scale)) in zip(keys, sorted(shapes.items())):
+    for k, (name, (shape, scale, *own)) in zip(keys,
+                                                sorted(shapes.items())):
         node = tree
         *path, leaf = name.split(".")
         for p in path:
             node = node.setdefault(p, {})
         node[leaf] = (jax.random.normal(k, shape, jnp.float32)
-                      * scale).astype(dt)
+                      * scale).astype(DTYPES[own[0]] if own else dt)
     return tree
 
 

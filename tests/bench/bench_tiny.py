@@ -1,6 +1,8 @@
 """A tiny cell of the benchmark for CPU tests: a copy of ``bench/`` with a
 two-layer configuration, small traffic mixes and their cells, under a
-BENCHMARK.json of its own."""
+BENCHMARK.json of its own; and a tiny routed-expert configuration added as
+files alone: its config, its model module and its reference module
+(``fixtures/configs/``), and one cell."""
 
 from __future__ import annotations
 
@@ -9,9 +11,11 @@ import shutil
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
+FIXTURES = Path(__file__).resolve().parent / "fixtures"
 
 CONFIG = {
     "name": "tiny", "source": "test", "reference": "qwen3_mita",
+    "model": "qwen3_dense",
     "hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "vocab_size": 251, "rope_theta": 1000000, "rms_norm_eps": 1e-06,
@@ -20,6 +24,15 @@ CONFIG = {
     # float32 compute: at this width bf16 rounding alone moves logits
     # by a good part of their spread
     "dtypes": {"params": "float32", "compute": "float32"}}
+
+# 8 experts, top-2, one shared expert; dropless routing (the module sets
+# the program's capacity factor to E / K)
+MOE_CONFIG = {
+    **{k: v for k, v in CONFIG.items() if k != "intermediate_size"},
+    "name": "tiny-moe", "reference": "qwen3_moe_mita", "model": "qwen3_moe",
+    "moe_intermediate_size": 64, "num_experts": 8, "num_experts_per_tok": 2,
+    "num_shared_experts": 1}
+MOE_CELL = "tiny-moe.tiny-closed"
 
 TRAFFIC = {
     "tiny-open": {"loop": "open", "rate_per_s": 6},
@@ -41,22 +54,29 @@ def make(tmp: Path, traffic: dict | None = None) -> Path:
     ``traffic``: extra traffic files, name -> parameters)."""
     shutil.copytree(ROOT / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    (tmp / "bench/configs/tiny.json").write_text(json.dumps(CONFIG))
+    for path in (FIXTURES / "configs").glob("*.py"):
+        shutil.copy(path, tmp / "bench/configs")
+    for spec in (CONFIG, MOE_CONFIG):
+        (tmp / f"bench/configs/{spec['name']}.json").write_text(
+            json.dumps(spec))
     mixes = {k: {**COMMON, **v} for k, v in
              {**TRAFFIC, **(traffic or {})}.items()}
     cells = []
     for name, spec in mixes.items():
         (tmp / f"bench/traffic/{name}.json").write_text(json.dumps(spec))
-        (tmp / f"bench/cells/tiny.{name}.json").write_text(json.dumps(
+    pairs = [("tiny", name) for name in mixes]
+    pairs.append(tuple(MOE_CELL.split(".")))
+    for config, name in pairs:
+        (tmp / f"bench/cells/{config}.{name}.json").write_text(json.dumps(
             {"slots": 3, "pool_tokens": 4096,
              "limits": {"logit_gap_mean": LIMIT,
                         "logit_gap_median": LIMIT}}))
-        cells.append({"name": f"tiny.{name}", "config": "tiny",
+        cells.append({"name": f"{config}.{name}", "config": config,
                       "traffic": name, "chips": 1, "why": "test"})
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    bench["configs"] = [{"name": "tiny", "source": "test",
-                         "file": "bench/configs/tiny.json", "reduced": [],
-                         "why": "test"}]
+    bench["configs"] = [{"name": c, "source": "test",
+                         "file": f"bench/configs/{c}.json", "reduced": [],
+                         "why": "test"} for c in ("tiny", "tiny-moe")]
     bench["workloads"] = cells
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)

@@ -1,13 +1,18 @@
 """The harness end to end on the CPU, at a tiny size: both loops run and
 come out correct, a traffic file dropped into bench/traffic/ is found
-without any edit, and the comparison that decides ``correct`` fails a
-token altered where it is produced, a decode step that leaves its state
-unchanged, and the float8 control."""
+without any edit, a routed-expert configuration added as files alone
+serves correct and is counted by its own model module, and the
+comparison that decides ``correct`` fails a token altered where it is
+produced, a decode step that leaves its state unchanged, and the float8
+control."""
+
+import importlib.util
 
 import numpy as np
 import pytest
 
 import bench_tiny
+from bench import window
 
 
 @pytest.fixture(scope="module")
@@ -32,6 +37,44 @@ def test_cell_runs_correct(root, workload):
     # reference's own first choices
     assert res["checks"]["logit_gap_mean"]["value"] == 0.0
     assert res["checks"]["logit_gap_median"]["value"] == 0.0
+
+
+def test_routed_expert_config_runs_correct_as_files(root, monkeypatch):
+    """``tiny-moe`` (8 experts, top-2, one shared expert) is its config,
+    model module and reference module in bench/configs/ and one cell: it
+    serves through the program's MoE layer and the reference's token by
+    token expert loop agrees, and ``step_mfu`` counts its operations with
+    that module's ``token_flops`` over the run's dispatches."""
+    seen = {}
+    read = window.reader
+
+    def capture(name, metrics_dir=window.METRICS):
+        fn = read(name, metrics_dir)
+
+        def keep(run):
+            seen["run"] = run
+            return fn(run)
+        return keep
+
+    monkeypatch.setattr(window, "reader", capture)
+    res = bench_tiny.run(root, bench_tiny.MOE_CELL)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+
+    run = seen["run"]
+    path = root / "bench/configs/qwen3_moe.py"
+    mspec = importlib.util.spec_from_file_location("moe_counts", path)
+    mod = importlib.util.module_from_spec(mspec)
+    mspec.loader.exec_module(mod)
+    ops = sum(mod.token_flops(run.spec, int(p), prompt=False, head=True)
+              for d in run.window_dispatches("decode") for p in d.positions)
+    ops += sum(mod.token_flops(run.spec, p, prompt=True, head=False)
+               for d in run.window_dispatches("prefill")
+               for t0, n in d.rows for p in range(int(t0), int(t0 + n)))
+    assert ops > 0
+    got = read("step_mfu", root / "bench/metrics")(run)
+    assert got == 100.0 * ops / (run.seconds * run.peak["bf16_flops_per_s"])
 
 
 @pytest.mark.parametrize("every", [5, 1])
